@@ -39,6 +39,16 @@ def _record(name: str, us: float, derived: str) -> None:
     _RESULTS[name] = {"us_per_call": round(us, 3), "derived": derived}
 
 
+def require_route(name: str, routed: KernelConfig, impl: str) -> None:
+    """Refuse to record a row under ``impl`` when the op's shapes would send
+    it to another implementation."""
+    if routed.resolved_impl() != impl:
+        raise RuntimeError(
+            f"{name}: these shapes route to impl={routed.resolved_impl()}, "
+            f"not the impl={impl} this bench records"
+        )
+
+
 def main() -> None:
     key = jax.random.PRNGKey(0)
     impl = default_config().resolved_impl()
@@ -100,8 +110,10 @@ def main() -> None:
     # training kernels above.
     r, np_, bs, kvh, hq, d = 8, 256, 16, 2, 8, 128
     mbk = 64
-    kp = jax.random.normal(jax.random.fold_in(key, 20), (np_ + 1, bs, kvh, d)) * 0.3
-    vp = jax.random.normal(jax.random.fold_in(key, 21), (np_ + 1, bs, kvh, d)) * 0.3
+    # kv-head-major pools, (NP, KV, BS, D), as models/attention.PagedAttnCache
+    kp = jax.random.normal(jax.random.fold_in(key, 20), (np_ + 1, kvh, bs, d)) * 0.3
+    vp = jax.random.normal(jax.random.fold_in(key, 21), (np_ + 1, kvh, bs, d)) * 0.3
+    require_route("paged_attention", ops.paged_impl(hq, kp.shape[1]), impl)
     tables = jax.random.randint(jax.random.fold_in(key, 22), (r, mbk), 0, np_)
     pos = jnp.full((r,), mbk * bs // 2, jnp.int32)
     qd = jax.random.normal(jax.random.fold_in(key, 23), (r, hq, d))
@@ -113,6 +125,7 @@ def main() -> None:
 
     cch = 32
     qc = jax.random.normal(jax.random.fold_in(key, 24), (r, cch, hq, d))
+    require_route("paged_chunk_attention", ops.paged_impl(hq, kp.shape[1]), impl)
     fnc = jax.jit(lambda *a: ops.paged_chunk_attention(*a, mode="causal"))
     usc = _time(fnc, qc, kp, vp, tables, pos)
     _record("kernel_paged_attn_chunk_r8_c32", usc,

@@ -9,6 +9,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 MODULES = [
     ("fig5", "benchmarks.fig5_latency"),          # Fig 5A/5B latency + blocking
@@ -27,6 +29,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated subset keys")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 
 from repro.core import pairing
 from repro.core.outer import OuterConfig
-from repro.parallel import compat
 from repro.launch import roofline as rf
 from repro.launch.mesh import make_test_mesh
 from repro.models import model as M
@@ -35,7 +34,7 @@ def main() -> None:
     perm = pairing.ppermute_pairs(0, plan.replicas)
     rep = jax.ShapeDtypeStruct((plan.replicas,), jnp.int32)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for method in ("noloco", "diloco"):
             ocfg = OuterConfig(method=method, alpha=0.5 if method == "noloco" else 0.3)
             fn = ST.build_outer_step(plan, mesh, pspecs, ocfg, perm)

@@ -9,8 +9,10 @@
 Layering: <name>.py (pl.pallas_call + BlockSpec, array-level), ref.py
 (pure-jnp twins + oracles), dispatch.py (KernelConfig + the op registry),
 ops.py (public custom_vjp'd wrappers the models/core/comm consumers call).
-Validated with interpret=True on CPU; TPU v5e is the TARGET (MXU-aligned 128
-blocks, VMEM tiling).  See DESIGN.md §6 for the dispatch table.
+Parity with the jnp twins is tested on CPU in interpret mode; every kernel
+also compiles for the TPU v5e (tests/test_chip_compile.py), and chip_smoke.py
+checks the main-path kernels against their twins on the chip.  See DESIGN.md
+§6 for the dispatch table.
 """
 
 from repro.kernels import dispatch, ops, ref

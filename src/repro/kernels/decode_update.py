@@ -15,8 +15,10 @@ Shapes are the serving-engine slot layout (R = request slots):
           → state' = decay·state + dtx ⊗ b,  y = Σ_n state'·c   ((R,HP,N), (R,HP))
 
 Both compute in f32 (the recurrent state is f32-resident in the engine) and
-tile the trailing dims at lane width.  Validated on CPU with interpret=True
-against the jnp twins in ref.py; the TPU is the TARGET.
+tile the trailing dims at lane width; the SSD step's per-slot vectors ride as
+(R, HP, 1) columns and (R, 1, N) rows so that every block's last two dims
+equal the array's, as the TPU tiling rules require.  Parity with the jnp
+twins in ref.py is tested in interpret mode; both compile for the TPU v5e.
 """
 
 from __future__ import annotations
@@ -28,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 BLOCK_W = 128   # lane-aligned width tile
 
@@ -65,7 +66,7 @@ def pallas_rglru_decode(
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, wp), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(h, a, b)
     return out[:, :w]
@@ -73,13 +74,13 @@ def pallas_rglru_decode(
 
 def _ssd_kernel(state_ref, decay_ref, dtx_ref, b_ref, c_ref, st_ref, y_ref):
     st = state_ref[0].astype(jnp.float32)        # (HP, N)
-    decay = decay_ref[0].astype(jnp.float32)     # (HP,)
-    dtx = dtx_ref[0].astype(jnp.float32)
-    b = b_ref[0].astype(jnp.float32)             # (N,)
-    c = c_ref[0].astype(jnp.float32)
-    new = st * decay[:, None] + dtx[:, None] * b[None, :]
+    decay = decay_ref[0].astype(jnp.float32)     # (HP, 1)
+    dtx = dtx_ref[0].astype(jnp.float32)         # (HP, 1)
+    b = b_ref[0].astype(jnp.float32)             # (1, N)
+    c = c_ref[0].astype(jnp.float32)             # (1, N)
+    new = st * decay + dtx * b
     st_ref[0] = new
-    y_ref[0] = new @ c
+    y_ref[0] = jnp.sum(new * c, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -94,25 +95,21 @@ def pallas_ssd_decode(
 ) -> tuple[jax.Array, jax.Array]:
     """One SSD step per slot: state' = decay·state + dtx⊗b, y = state'·c."""
     r, hp, n = state.shape
-    out = pl.pallas_call(
+    # per-slot vectors ride as (R, HP, 1) columns / (R, 1, N) rows so every
+    # block's last two dims equal the array's (the TPU tiling rule)
+    col = pl.BlockSpec((1, hp, 1), lambda ri: (ri, 0, 0))
+    row = pl.BlockSpec((1, 1, n), lambda ri: (ri, 0, 0))
+    full = pl.BlockSpec((1, hp, n), lambda ri: (ri, 0, 0))
+    st, y = pl.pallas_call(
         _ssd_kernel,
         grid=(r,),
-        in_specs=[
-            pl.BlockSpec((1, hp, n), lambda ri: (ri, 0, 0)),
-            pl.BlockSpec((1, hp), lambda ri: (ri, 0)),
-            pl.BlockSpec((1, hp), lambda ri: (ri, 0)),
-            pl.BlockSpec((1, n), lambda ri: (ri, 0)),
-            pl.BlockSpec((1, n), lambda ri: (ri, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hp, n), lambda ri: (ri, 0, 0)),
-            pl.BlockSpec((1, hp), lambda ri: (ri, 0)),
-        ],
+        in_specs=[full, col, col, row, row],
+        out_specs=[full, col],
         out_shape=[
             jax.ShapeDtypeStruct((r, hp, n), jnp.float32),
-            jax.ShapeDtypeStruct((r, hp), jnp.float32),
+            jax.ShapeDtypeStruct((r, hp, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(state, decay, dtx, b, c)
-    return out[0], out[1]
+    )(state, decay[:, :, None], dtx[:, :, None], b[:, None], c[:, None])
+    return st, y[:, :, 0]

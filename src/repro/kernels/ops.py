@@ -10,7 +10,7 @@ recompute, so gradients are exact and memory-bounded regardless of which
 implementation ran the forward.
 
 ``interpret`` resolution: True off-TPU, False on TPU (overridable via
-``KernelConfig.interpret``) — this box is CPU-only and the TPU is the TARGET.
+``KernelConfig.interpret``).
 """
 
 from __future__ import annotations
@@ -33,6 +33,7 @@ __all__ = [
     "int8_dequantize",
     "paged_attention",
     "paged_chunk_attention",
+    "paged_impl",
     "rglru_decode",
     "ssd_decode",
 ]
@@ -294,10 +295,22 @@ def noloco_update_pytree(
 # ---------------------------------------------------------------------------
 
 
+def paged_impl(
+    q_heads: int, kv_heads: int, config: KernelConfig | None = None
+) -> KernelConfig:
+    """The implementation the paged ops run for ``q_heads`` query heads over
+    a pool of ``kv_heads``: the resolved config, except that the Pallas
+    kernels fold whole GQA groups, so H % KV != 0 routes to the jnp twin."""
+    impl, interpret = _resolve(config)
+    if impl == "pallas" and q_heads % kv_heads == 0:
+        return KernelConfig("pallas", interpret)
+    return KernelConfig("jnp")
+
+
 def paged_attention(
     q: jax.Array,             # (R, H, D) one decode token per request slot
-    k_pages: jax.Array,       # (NP, BS, KV, D) page pool
-    v_pages: jax.Array,       # (NP, BS, KV, D)
+    k_pages: jax.Array,       # (NP, KV, BS, D) page pool
+    v_pages: jax.Array,       # (NP, KV, BS, D)
     block_tables: jax.Array,  # (R, MB) int32 page ids per slot
     positions: jax.Array,     # (R,) int32 current token position per slot
     *,
@@ -311,21 +324,16 @@ def paged_attention(
     sliding window for local layers), so trash-page writes and unallocated
     table entries never contribute.  The Pallas kernel requires H % KV == 0
     (GQA folding); ragged head counts route to the jnp twin."""
-    impl, interpret = _resolve(config)
-    h, kvh = q.shape[1], k_pages.shape[2]
-    if impl == "pallas" and h % kvh == 0:
-        return dispatch("paged_attention", KernelConfig("pallas", interpret))(
-            q, k_pages, v_pages, block_tables, positions, mode=mode, window=window
-        )
-    return dispatch("paged_attention", KernelConfig("jnp"))(
+    impl = paged_impl(q.shape[1], k_pages.shape[1], config)
+    return dispatch("paged_attention", impl)(
         q, k_pages, v_pages, block_tables, positions, mode=mode, window=window
     )
 
 
 def paged_chunk_attention(
     q: jax.Array,             # (R, C, H, D) one prefill chunk per request slot
-    k_pages: jax.Array,       # (NP, BS, KV, D) page pool
-    v_pages: jax.Array,       # (NP, BS, KV, D)
+    k_pages: jax.Array,       # (NP, KV, BS, D) page pool
+    v_pages: jax.Array,       # (NP, KV, BS, D)
     block_tables: jax.Array,  # (R, MB) int32 page ids per slot
     positions: jax.Array,     # (R,) int32 base position of chunk token 0
     *,
@@ -340,13 +348,8 @@ def paged_chunk_attention(
     length scatter to the trash page and their output rows are discarded, so
     ONE fixed-C program covers every prompt-length mix.  The Pallas kernel
     requires H % KV == 0; ragged head counts route to the jnp twin."""
-    impl, interpret = _resolve(config)
-    h, kvh = q.shape[2], k_pages.shape[2]
-    if impl == "pallas" and h % kvh == 0:
-        return dispatch("paged_chunk_attention", KernelConfig("pallas", interpret))(
-            q, k_pages, v_pages, block_tables, positions, mode=mode, window=window
-        )
-    return dispatch("paged_chunk_attention", KernelConfig("jnp"))(
+    impl = paged_impl(q.shape[2], k_pages.shape[1], config)
+    return dispatch("paged_chunk_attention", impl)(
         q, k_pages, v_pages, block_tables, positions, mode=mode, window=window
     )
 

@@ -4,7 +4,7 @@ gathered from fixed-size pages through per-request block tables.
 
 This is the serving twin of kernels/flash_attention.py: same online-softmax
 recurrence, but the KV sequence is PHYSICALLY SCATTERED across a page pool
-(NP, BS, KV, D) and addressed logically by ``block_tables (R, MB)``.  The
+(NP, KV, BS, D) and addressed logically by ``block_tables (R, MB)``.  The
 tables (plus per-request positions) ride in as SCALAR-PREFETCH operands
 (``pltpu.PrefetchScalarGridSpec``), so each grid step's K/V page index is
 known before the body runs and the DMA fetches exactly one page per step —
@@ -23,10 +23,16 @@ Fully-masked pages self-heal exactly as in the flash kernel: their p=1 rows
 are wiped by corr=0 once a finite-max page arrives, and for causal decode
 page 0 is always valid.
 
-VMEM per program: q (G, D) + k/v (BS, D) + acc (G, D) f32 + m/l (G,)
+The pool is laid out kv-head-major so that one grid step's K/V tile is a
+whole (BS, D) page of one kv head: the block's last two dims equal the
+array's, which is what the TPU tiling rules accept for any BS and D.  The
+softmax statistics m/l are (G, 1) columns for the same reason.
+
+VMEM per program: q (G, D) + k/v (BS, D) + acc (G, D) f32 + m/l (G, 1)
 ≈ a few KiB for typical (G ≤ 8, BS ≤ 64, D ≤ 256) — paging keeps the decode
-working set independent of context length.  Validated on CPU with
-interpret=True against ref.jnp_paged_attention; the TPU is the TARGET.
+working set independent of context length.  Parity with
+ref.jnp_paged_attention is tested in interpret mode; tests/test_chip_compile.py
+compiles both kernels for the TPU v5e.
 
 CHUNKED PREFILL (``pallas_paged_chunk_attention``) is the same kernel shape
 with C query tokens per slot instead of one: query row c of slot r sits at
@@ -49,7 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -76,8 +81,8 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (BS, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                # (BS, D)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = q @ k.T                                        # (G, BS)
 
@@ -90,17 +95,17 @@ def _kernel(
         valid &= kv_pos > pos - window
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                # (rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + p @ v
     m_ref[...] = m_new
 
     @pl.when(bi == nb - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...][:, None], 1e-30)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -109,8 +114,8 @@ def _kernel(
 )
 def pallas_paged_attention(
     q: jax.Array,             # (R, H, D) — one decode token per request slot
-    k_pages: jax.Array,       # (NP, BS, KV, D)
-    v_pages: jax.Array,       # (NP, BS, KV, D)
+    k_pages: jax.Array,       # (NP, KV, BS, D)
+    v_pages: jax.Array,       # (NP, KV, BS, D)
     block_tables: jax.Array,  # (R, MB) int32
     positions: jax.Array,     # (R,) int32
     *,
@@ -121,7 +126,7 @@ def pallas_paged_attention(
     """Paged decode attention at model layout — requires H % KV == 0 (the ops
     wrapper routes non-divisible head counts to the jnp twin)."""
     r, h, d = q.shape
-    np_, bs, kvh, _ = k_pages.shape
+    np_, kvh, bs, _ = k_pages.shape
     mb = block_tables.shape[1]
     if h % kvh:
         raise ValueError(
@@ -137,10 +142,10 @@ def pallas_paged_attention(
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda ri, hi, bi, tbl, pos: (ri, hi, 0, 0)),
             pl.BlockSpec(
-                (1, bs, 1, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], 0, hi, 0)
+                (1, 1, bs, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], hi, 0, 0)
             ),
             pl.BlockSpec(
-                (1, bs, 1, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], 0, hi, 0)
+                (1, 1, bs, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], hi, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -148,8 +153,8 @@ def pallas_paged_attention(
         ),
         scratch_shapes=[
             pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -158,7 +163,7 @@ def pallas_paged_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -189,8 +194,8 @@ def _chunk_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (C*G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (BS, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                # (BS, D)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = q @ k.T                                        # (C*G, BS)
 
@@ -206,17 +211,17 @@ def _chunk_kernel(
         valid &= kv_pos > q_pos - window
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                # (rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + p @ v
     m_ref[...] = m_new
 
     @pl.when(bi == nb - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...][:, None], 1e-30)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -225,8 +230,8 @@ def _chunk_kernel(
 )
 def pallas_paged_chunk_attention(
     q: jax.Array,             # (R, C, H, D) — one prefill chunk per slot
-    k_pages: jax.Array,       # (NP, BS, KV, D)
-    v_pages: jax.Array,       # (NP, BS, KV, D)
+    k_pages: jax.Array,       # (NP, KV, BS, D)
+    v_pages: jax.Array,       # (NP, KV, BS, D)
     block_tables: jax.Array,  # (R, MB) int32
     positions: jax.Array,     # (R,) int32 — base position of chunk token 0
     *,
@@ -239,7 +244,7 @@ def pallas_paged_chunk_attention(
     r queries at position ``positions[r] + c``; rows past the slot's ragged
     length produce garbage that the caller discards."""
     r, c, h, d = q.shape
-    np_, bs, kvh, _ = k_pages.shape
+    np_, kvh, bs, _ = k_pages.shape
     mb = block_tables.shape[1]
     if h % kvh:
         raise ValueError(
@@ -259,10 +264,10 @@ def pallas_paged_chunk_attention(
                 (1, 1, c * g, d), lambda ri, hi, bi, tbl, pos: (ri, hi, 0, 0)
             ),
             pl.BlockSpec(
-                (1, bs, 1, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], 0, hi, 0)
+                (1, 1, bs, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], hi, 0, 0)
             ),
             pl.BlockSpec(
-                (1, bs, 1, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], 0, hi, 0)
+                (1, 1, bs, d), lambda ri, hi, bi, tbl, pos: (tbl[ri, bi], hi, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -270,8 +275,8 @@ def pallas_paged_chunk_attention(
         ),
         scratch_shapes=[
             pltpu.VMEM((c * g, d), jnp.float32),
-            pltpu.VMEM((c * g,), jnp.float32),
-            pltpu.VMEM((c * g,), jnp.float32),
+            pltpu.VMEM((c * g, 1), jnp.float32),
+            pltpu.VMEM((c * g, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -285,7 +290,7 @@ def pallas_paged_chunk_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -12,6 +12,10 @@ compression fused on the wire path.
 Layout contract (shared with ref.jnp_int8_quantize): input is the
 already-padded 2-D (NC, CHUNK) view of the payload; the byte-level wire
 packing (values ‖ bitcast metadata) stays in comm/compress.py.
+
+TPU tiling: the per-chunk (scale, min) pair travels through the kernel as
+(NC, 1) columns, so every block is 2-D with a full-width last dim, and ROWS
+is 32 because a uint8 tile spans 32 sublanes.
 """
 
 from __future__ import annotations
@@ -22,23 +26,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS = 8  # chunk rows per grid step: (8, 1024) f32 tile = 32 KiB VMEM
+ROWS = 32  # chunk rows per grid step: (32, 1024) f32 tile = 128 KiB VMEM
 
 
 def _quant_kernel(x_ref, q_ref, scale_ref, lo_ref):
     x = x_ref[...].astype(jnp.float32)              # (ROWS, CHUNK)
-    lo = jnp.min(x, axis=1)
-    scale = (jnp.max(x, axis=1) - lo) / 255.0
+    lo = jnp.min(x, axis=1, keepdims=True)          # (ROWS, 1)
+    scale = (jnp.max(x, axis=1, keepdims=True) - lo) / 255.0
     safe = jnp.where(scale > 0.0, scale, 1.0)
-    q = jnp.clip(jnp.round((x - lo[:, None]) / safe[:, None]), 0.0, 255.0)
-    q_ref[...] = q.astype(jnp.uint8)
+    q = jnp.clip(jnp.round((x - lo) / safe), 0.0, 255.0)
+    # Mosaic casts between f32 and uint8 only through int32
+    q_ref[...] = q.astype(jnp.int32).astype(jnp.uint8)
     scale_ref[...] = safe
     lo_ref[...] = lo
 
 
 def _dequant_kernel(q_ref, scale_ref, lo_ref, x_ref):
-    q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = q * scale_ref[...][:, None] + lo_ref[...][:, None]
+    q = q_ref[...].astype(jnp.int32).astype(jnp.float32)
+    x_ref[...] = q * scale_ref[...] + lo_ref[...]
 
 
 def _pad_rows(x: jax.Array, rows: int) -> tuple[jax.Array, int]:
@@ -58,20 +63,17 @@ def pallas_int8_quantize(
     chunk = x.shape[1]
     grid = (xp.shape[0] // ROWS,)
     spec2d = pl.BlockSpec((ROWS, chunk), lambda i: (i, 0))
-    spec1d = pl.BlockSpec((ROWS,), lambda i: (i,))
+    spec_col = pl.BlockSpec((ROWS, 1), lambda i: (i, 0))
+    col = jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32)
     q, scale, lo = pl.pallas_call(
         _quant_kernel,
         grid=grid,
         in_specs=[spec2d],
-        out_specs=[spec2d, spec1d, spec1d],
-        out_shape=[
-            jax.ShapeDtypeStruct(xp.shape, jnp.uint8),
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
-        ],
+        out_specs=[spec2d, spec_col, spec_col],
+        out_shape=[jax.ShapeDtypeStruct(xp.shape, jnp.uint8), col, col],
         interpret=interpret,
     )(xp)
-    return q[:nc], scale[:nc], lo[:nc]
+    return q[:nc], scale[:nc, 0], lo[:nc, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -80,16 +82,16 @@ def pallas_int8_dequantize(
 ) -> jax.Array:
     """(q uint8 (NC,CHUNK), scale (NC,), lo (NC,)) → f32 (NC, CHUNK)."""
     qp, nc = _pad_rows(q, ROWS)
-    sp, _ = _pad_rows(scale, ROWS)
-    lp, _ = _pad_rows(lo, ROWS)
+    sp, _ = _pad_rows(scale[:, None], ROWS)
+    lp, _ = _pad_rows(lo[:, None], ROWS)
     chunk = q.shape[1]
     grid = (qp.shape[0] // ROWS,)
     spec2d = pl.BlockSpec((ROWS, chunk), lambda i: (i, 0))
-    spec1d = pl.BlockSpec((ROWS,), lambda i: (i,))
+    spec_col = pl.BlockSpec((ROWS, 1), lambda i: (i, 0))
     x = pl.pallas_call(
         _dequant_kernel,
         grid=grid,
-        in_specs=[spec2d, spec1d, spec1d],
+        in_specs=[spec2d, spec_col, spec_col],
         out_specs=spec2d,
         out_shape=jax.ShapeDtypeStruct(qp.shape, jnp.float32),
         interpret=interpret,

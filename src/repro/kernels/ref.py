@@ -126,10 +126,19 @@ def jnp_flash_attention(
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d).astype(q.dtype)
 
 
+def _gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """Dense (R, MB·BS, KV, D) view of each slot's pages from a (NP, KV, BS,
+    D) pool through its (R, MB) block table."""
+    r, mb = block_tables.shape
+    _, kvh, bs, d = pages.shape
+    x = jnp.take(pages, block_tables, axis=0)            # (R, MB, KV, BS, D)
+    return x.transpose(0, 1, 3, 2, 4).reshape(r, mb * bs, kvh, d)
+
+
 def jnp_paged_attention(
     q: jax.Array,             # (R, H, D) — one decode token per request slot
-    k_pages: jax.Array,       # (NP, BS, KV, D) — fixed-size KV pages (last = trash)
-    v_pages: jax.Array,       # (NP, BS, KV, D)
+    k_pages: jax.Array,       # (NP, KV, BS, D) — fixed-size KV pages (last = trash)
+    v_pages: jax.Array,       # (NP, KV, BS, D)
     block_tables: jax.Array,  # (R, MB) int32 page index per logical block
     positions: jax.Array,     # (R,) int32 position of the incoming token
     *,
@@ -148,12 +157,10 @@ def jnp_paged_attention(
     past the request's context, unallocated table entries and the trash page
     are all masked out by position alone."""
     r, h, d = q.shape
-    bs, kvh = k_pages.shape[1], k_pages.shape[2]
+    kvh, bs = k_pages.shape[1], k_pages.shape[2]
     mb = block_tables.shape[1]
-    k = jnp.take(k_pages, block_tables, axis=0)          # (R, MB, BS, KV, D)
-    v = jnp.take(v_pages, block_tables, axis=0)
-    k = k.reshape(r, mb * bs, kvh, d)
-    v = v.reshape(r, mb * bs, kvh, d)
+    k = _gather_pages(k_pages, block_tables)             # (R, MB·BS, KV, D)
+    v = _gather_pages(v_pages, block_tables)
     if h % kvh:
         head_map = (jnp.arange(h) * kvh) // h
         k = jnp.take(k, head_map, axis=2)
@@ -177,8 +184,8 @@ def jnp_paged_attention(
 
 def jnp_paged_chunk_attention(
     q: jax.Array,             # (R, C, H, D) — one prefill chunk per slot
-    k_pages: jax.Array,       # (NP, BS, KV, D)
-    v_pages: jax.Array,       # (NP, BS, KV, D)
+    k_pages: jax.Array,       # (NP, KV, BS, D)
+    v_pages: jax.Array,       # (NP, KV, BS, D)
     block_tables: jax.Array,  # (R, MB) int32
     positions: jax.Array,     # (R,) int32 — base position of chunk token 0
     *,
@@ -195,12 +202,10 @@ def jnp_paged_chunk_attention(
     rows past the slot's valid length produce garbage that the caller
     discards, and their K/V were scattered to the trash page."""
     r, c, h, d = q.shape
-    bs, kvh = k_pages.shape[1], k_pages.shape[2]
+    kvh, bs = k_pages.shape[1], k_pages.shape[2]
     mb = block_tables.shape[1]
-    k = jnp.take(k_pages, block_tables, axis=0)          # (R, MB, BS, KV, D)
-    v = jnp.take(v_pages, block_tables, axis=0)
-    k = k.reshape(r, mb * bs, kvh, d)
-    v = v.reshape(r, mb * bs, kvh, d)
+    k = _gather_pages(k_pages, block_tables)             # (R, MB·BS, KV, D)
+    v = _gather_pages(v_pages, block_tables)
     if h % kvh:
         head_map = (jnp.arange(h) * kvh) // h
         k = jnp.take(k, head_map, axis=2)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 BLOCK_S = 256   # sequence chunk per grid step
 BLOCK_W = 128   # lane-aligned width tile
@@ -54,10 +53,9 @@ def _kernel(a_ref, b_ref, h_ref, carry_ref, *, block_s: int):
         big_a = big_a * a_shift
         off *= 2
 
-    h_in = carry_ref[...]                   # (W,) state entering this chunk
-    h = big_b + big_a * h_in[None, :]
+    h = big_b + big_a * carry_ref[...]      # carry: (1, W) state entering
     h_ref[0] = h.astype(h_ref.dtype)
-    carry_ref[...] = h[-1]
+    carry_ref[...] = jax.lax.slice_in_dim(h, block_s - 1, block_s, axis=0)
 
 
 @functools.partial(
@@ -89,8 +87,8 @@ def pallas_rglru_scan(
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -10,6 +10,12 @@ Per (batch, chunk, head) program:
 The inter-chunk state recurrence is a cheap sequential scan left in jnp
 (models/ssd.py); this kernel owns the O(Q²) work.  Q = ssm_chunk (128),
 P = head_dim (64), N = d_state (128): VMEM ≈ Q·(P+2N)·4 + Q²·4 ≈ 250 KiB.
+
+TPU layout: heads move ahead of the chunk positions outside the kernel, so
+each block's last two dims are a whole (Q, P) / (Q, 1) / (1, Q) tile; dt
+rides in both as a column and as a row, which lets the inclusive cumsum
+be two masked reductions instead of a scan; the per-head decay rates sit
+in SMEM.
 """
 
 from __future__ import annotations
@@ -19,32 +25,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref):
-    x = x_ref[0, 0, :, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0, :, 0].astype(jnp.float32)      # (Q,)
-    a = a_ref[0].astype(jnp.float32)                 # scalar
+def _kernel(a_ref, x_ref, dtc_ref, dtr_ref, b_ref, c_ref, y_ref, state_ref):
+    a = a_ref[pl.program_id(2)]                      # scalar (SMEM)
+    x = x_ref[0, 0, 0].astype(jnp.float32)           # (Q, P)
+    dt_col = dtc_ref[0, 0, 0].astype(jnp.float32)    # (Q, 1)
+    dt_row = dtr_ref[0, 0, 0].astype(jnp.float32)    # (1, Q)
     b = b_ref[0, 0].astype(jnp.float32)              # (Q, N)
     c = c_ref[0, 0].astype(jnp.float32)              # (Q, N)
 
     q = x.shape[0]
-    da = dt * a                                       # (Q,)
-    cums = jnp.cumsum(da)                             # inclusive
-
-    diff = cums[:, None] - cums[None, :]              # (Q, Q)
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    l_kern = jnp.where(ii >= jj, jnp.exp(diff), 0.0)
+    lower = ii >= jj
+    # inclusive cumsum of dt·a, as a column (cums_i) and as a row (cums_j)
+    cums_col = jnp.sum(jnp.where(lower, dt_row * a, 0.0), axis=1, keepdims=True)
+    cums_row = jnp.sum(jnp.where(ii <= jj, dt_col * a, 0.0), axis=0, keepdims=True)
 
-    xdt = x * dt[:, None]                             # (Q, P)
-    scores = c @ b.T                                  # (Q, Q)
-    y = (scores * l_kern) @ xdt                       # (Q, P)
+    l_kern = jnp.where(lower, jnp.exp(cums_col - cums_row), 0.0)   # (Q, Q)
+    xdt = x * dt_col                                                # (Q, P)
+    scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))   # C Bᵀ
+    y = (scores * l_kern) @ xdt                                     # (Q, P)
 
-    decay = jnp.exp(cums[-1] - cums)                  # (Q,)
-    state = (b * (decay * dt)[:, None]).T @ x         # (N, P)
+    last = jax.lax.slice_in_dim(cums_row, q - 1, q, axis=1)        # (1, 1)
+    w = b * (jnp.exp(last - cums_col) * dt_col)                     # (Q, N)
+    state = jax.lax.dot_general(w, x, (((0,), (0,)), ((), ())))    # (N, P)
 
-    y_ref[0, 0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0, 0] = y.astype(y_ref.dtype)
     state_ref[0, 0, 0] = state.astype(state_ref.dtype)
 
 
@@ -61,26 +70,31 @@ def ssd_chunk_kernel(
     """Returns (y_diag (B,NC,Q,H,P), states (B,NC,H,N,P))."""
     bsz, nc, qlen, h, p = x.shape
     n = b_mat.shape[-1]
+    xh = x.transpose(0, 1, 3, 2, 4)                  # (B, NC, H, Q, P)
+    dth = dt.transpose(0, 1, 3, 2)                   # (B, NC, H, Q)
 
-    # broadcast B/C over heads at the BlockSpec level (no materialized copy)
+    head_tile = lambda *tile: pl.BlockSpec(
+        (1, 1, 1) + tile, lambda b, c, hh: (b, c, hh, 0, 0)
+    )
+    # B/C are shared by every head: the BlockSpec re-reads them per head
+    # instead of materializing a broadcast copy
+    shared = pl.BlockSpec((1, 1, qlen, n), lambda b, c, hh: (b, c, 0, 0))
     y, states = pl.pallas_call(
         _kernel,
         grid=(bsz, nc, h),
         in_specs=[
-            pl.BlockSpec((1, 1, qlen, 1, p), lambda b, c, hh: (b, c, 0, hh, 0)),
-            pl.BlockSpec((1, 1, qlen, 1), lambda b, c, hh: (b, c, 0, hh)),
-            pl.BlockSpec((1,), lambda b, c, hh: (hh,)),
-            pl.BlockSpec((1, 1, qlen, n), lambda b, c, hh: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, qlen, n), lambda b, c, hh: (b, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            head_tile(qlen, p),
+            head_tile(qlen, 1),
+            head_tile(1, qlen),
+            shared,
+            shared,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, qlen, 1, p), lambda b, c, hh: (b, c, 0, hh, 0)),
-            pl.BlockSpec((1, 1, 1, n, p), lambda b, c, hh: (b, c, hh, 0, 0)),
-        ],
+        out_specs=[head_tile(qlen, p), head_tile(n, p)],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, nc, qlen, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, nc, h, qlen, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, nc, h, n, p), jnp.float32),
         ],
         interpret=interpret,
-    )(x, dt, a, b_mat, c_mat)
-    return y, states
+    )(a.astype(jnp.float32), xh, dth[..., None], dth[..., None, :], b_mat, c_mat)
+    return y.transpose(0, 1, 3, 2, 4), states

@@ -35,11 +35,11 @@ from repro.configs.shapes import SHAPES, InputShape, input_specs, shape_skips
 from repro.core.outer import OuterConfig
 from repro.core import pairing
 from repro.launch import roofline as rf
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_api
 from repro.models.common import unzip
 from repro.optim import AdamWConfig
-from repro.parallel import compat
 from repro.parallel import plans as plans_lib
 from repro.parallel import steps as steps_lib
 
@@ -75,7 +75,7 @@ def _build_lowered(cfg, plan, shape: InputShape, kind: str, mesh):
     theta_abs, _ = unzip(params_abs)
     specs = input_specs(cfg, shape)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opt_abs = jax.eval_shape(
                 lambda v: steps_lib.init_opt_state(v, plan.replicas), theta_abs
@@ -229,6 +229,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true", help="skip depth extrapolation")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = []
     if args.mesh in ("single", "both"):

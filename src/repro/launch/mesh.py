@@ -11,23 +11,25 @@ this module never touches jax device state; the dry-run sets
 
 from __future__ import annotations
 
-from repro.parallel import compat
+import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_test_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_test_mesh"]
 
 
-def _mk(shape, axes):
-    return compat.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """A mesh over the visible devices with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 4, model: int = 2, pod: int | None = None):
     """Small host-device mesh for CPU tests (device count forced upstream)."""
     if pod:
-        return _mk((pod, data, model), ("pod", "data", "model"))
-    return _mk((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -33,10 +33,10 @@ import jax.numpy as jnp
 from repro.configs import registry
 from repro.configs.shapes import SHAPES
 from repro.core import pairing
-from repro.parallel import compat
 from repro.core.outer import OuterConfig
 from repro.launch import dryrun as dr
 from repro.launch import roofline as rf
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_api
 from repro.models.common import unzip
@@ -57,7 +57,7 @@ def outer_variant(arch: str, overlapped: bool, mesh) -> dict:
     ocfg = OuterConfig(method="noloco")
     model_size = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         rep_sh = jax.ShapeDtypeStruct((plan.replicas,), jnp.int32)
         if not overlapped:
             fn = steps_lib.build_outer_step(plan, mesh, pspecs, ocfg, perm)
@@ -151,6 +151,7 @@ def main() -> None:
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--shape", default="train_4k")
     args = ap.parse_args()
+    enable_compile_cache()
     mesh = make_production_mesh(multi_pod=False)
 
     if args.variant in ("outer_baseline", "outer_overlap"):

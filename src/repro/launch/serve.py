@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import add_engine_flags, kernel_config_from_args
 from repro.models import model as M
 from repro.models.common import values_of
@@ -137,7 +138,8 @@ def serve_run(
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the reduced (smoke) variant of the arch")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-batch", type=int, default=4,
                     help="decode slots (concurrent requests)")
@@ -181,6 +183,7 @@ def main() -> None:
                          "(0 = tokens only surface at request finish)")
     add_engine_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     kcfg = kernel_config_from_args(args)
 
     cfg = registry.get_config(args.arch)

@@ -30,6 +30,7 @@ from repro.core import OuterConfig, TrainerConfig
 from repro.data import LoaderConfig
 from repro.kernels import dispatch as kernel_dispatch
 from repro.kernels.dispatch import KernelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig, warmup_cosine
 from repro.train import GossipProgram, LoopConfig, make_loop
@@ -200,6 +201,7 @@ def main() -> None:
     ap.add_argument("--out", default=None)
     add_engine_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     kernel_config_from_args(args)  # process-wide default (codec paths etc.)
 
     cfg = registry.get_config(args.arch)

@@ -15,10 +15,10 @@ runtime, replayed by the same :class:`~repro.sim.SimCluster`, with rejoin
 warm-start performed over the mesh and the membership epoch riding in the
 checkpoint — resume-after-churn reproduces the trajectory exactly.
 
-On this CPU box it runs on forced host devices for validation:
+On a CPU it runs on forced host devices for validation:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
-        python -m repro.launch.train_distributed --data 4 --model 2 --steps 40 \
+        python -m repro.launch.train_distributed --reduced --data 4 --model 2 --steps 40 \
         --ckpt-dir /tmp/dist0 --ckpt-every 20 --resume --log-jsonl /tmp/dist0.jsonl
 
 On TPU the same code drives the production mesh (launch/mesh.py).
@@ -44,12 +44,13 @@ from repro.configs import registry
 from repro.core.elastic import ElasticContext
 from repro.core.outer import OuterConfig, StreamSchedule
 from repro.kernels.dispatch import KernelConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.data import LoaderConfig
 from repro.models import model as model_api
 from repro.models.common import unzip
 from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig
-from repro.parallel import compat
 from repro.parallel import plans as plans_lib
 from repro.parallel import steps as steps_lib
 
@@ -109,7 +110,7 @@ class DistributedTrainer:
         params = model_api.init_params(jax.random.PRNGKey(self.seed), self.cfg)
         stacked = steps_lib.stack_replicas(params, self.plan.replicas)
         vals, _ = unzip(stacked)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.bundle = steps_lib.build_train_step(
                 self.cfg, self.plan, self.mesh, stacked, batch_example, self.inner_cfg
             )
@@ -217,7 +218,7 @@ class DistributedTrainer:
                 self._take_rows(state["theta"], ids),
                 self._take_rows(state["opt"], ids),
             )
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             batch = jax.device_put(batch, plans_lib.shardings(self.mesh, self._bspecs))
             theta, opt, metrics = self.bundle.step_fn(state["theta"], state["opt"], batch)
             if snap is not None:
@@ -267,7 +268,7 @@ class DistributedTrainer:
                     outer_index, plan.participants, self.elastic.partition
                 )
         t0 = time.time()
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             theta, phi, delta, step_c = fn(
                 state["theta"], state["phi"], state["delta"], state["outer_step"]
             )
@@ -327,7 +328,7 @@ class DistributedTrainer:
                     update_mask=update, staleness=stale_host,
                 )
         t0 = time.time()
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             theta, phi, delta, step_c = fn(
                 state["theta"], state["phi"], state["delta"], state["outer_step"]
             )
@@ -371,7 +372,7 @@ class DistributedTrainer:
                 # for THIS sync and none was issued for the next one
                 fn, info = self._all_absent_program(i)
                 t0 = time.time()
-                with compat.set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     theta, phi, delta, step_c = fn(
                         state["theta"], state["phi"], state["delta"],
                         state["outer_step"],
@@ -405,7 +406,7 @@ class DistributedTrainer:
             presend_index=presend_index, presend_membership=presend_membership,
         )
         t0 = time.time()
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             if overlap:
                 theta, phi, delta, phi_pre, step_c = fn(
                     state["theta"], state["phi"], state["delta"],
@@ -464,7 +465,7 @@ class DistributedTrainer:
         if key not in self.pool._programs:
             self.pool.misses += 1
             t0 = time.time()
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.pool._programs[key] = steps_lib.build_outer_step(
                     self.plan, self.mesh, self.bundle.pspecs, self.outer_cfg,
                     [(i, i) for i in range(world)],
@@ -488,7 +489,7 @@ class DistributedTrainer:
 
     def eval_loss(self, state, batch):
         """Grad-free per-replica losses (R,) via the bundle's eval program."""
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             batch = jax.device_put(batch, plans_lib.shardings(self.mesh, self._bspecs))
             return self.bundle.eval_fn(state["theta"], batch)
 
@@ -504,6 +505,8 @@ def main() -> None:
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-small-125m")
+    ap.add_argument("--reduced", action="store_true",
+                    help="train the reduced (smoke) variant of the arch")
     ap.add_argument("--data", type=int, default=4)
     ap.add_argument("--model", type=int, default=2)
     ap.add_argument("--steps", type=int, default=40)
@@ -539,6 +542,7 @@ def main() -> None:
                          "discounts it by 1/(1+τ)")
     add_engine_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if jax.device_count() < args.data * args.model:
         raise SystemExit(
@@ -549,11 +553,11 @@ def main() -> None:
     # went stale (membership epoch advanced) falls back to blocking for that
     # stream only — no hard error anymore
     overlap = args.overlap or args.stream_count > 1
-    mesh = compat.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = make_mesh((args.data, args.model), ("data", "model"))
     kcfg = kernel_config_from_args(args)
-    cfg = registry.get_config(args.arch).reduced(
-        vocab_size=512, dtype="float32", remat=False, kernels=kcfg
-    )
+    cfg = dataclasses.replace(registry.get_config(args.arch), kernels=kcfg)
+    if args.reduced:
+        cfg = cfg.reduced(vocab_size=min(cfg.vocab_size, 512), remat=False, dtype="float32")
     plan = plans_lib.make_plan("gossip_dp", mesh, shape_kind="train")
 
     elastic = None

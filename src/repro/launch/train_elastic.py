@@ -26,6 +26,7 @@ from repro.comm import CommConfig
 from repro.configs import registry
 from repro.data import LoaderConfig
 from repro.kernels.dispatch import KernelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import add_engine_flags, kernel_config_from_args, method_config
 from repro.models.config import ModelConfig
 from repro.sim import FaultPlan, SimCluster
@@ -156,6 +157,7 @@ def main() -> None:
     ap.add_argument("--out", default=None)
     add_engine_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     kernel_config_from_args(args)
 
     cfg = registry.get_config(args.arch)

@@ -21,6 +21,7 @@ from repro.comm import CommConfig
 from repro.configs import registry
 from repro.core.outer import OuterConfig
 from repro.data import LoaderConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamWConfig
 from repro.pipeline import PipelineTrainer
 from repro.train import LoopConfig, PipelineProgram, make_loop
@@ -48,6 +49,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     add_engine_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     import dataclasses
 

@@ -189,7 +189,8 @@ class PagedAttnCache:
     """Serving KV cache: a pool of fixed-size pages shared by all request
     slots, addressed through the per-slot block tables in :class:`PagedView`.
 
-    ``k_pages``/``v_pages`` are (num_pages + 1, page_size, KV, D); the LAST
+    ``k_pages``/``v_pages`` are (num_pages + 1, KV, page_size, D) — kv-head
+    major, so one kv head's page is a contiguous (page_size, D) tile; the LAST
     page is the TRASH page — decode steps of inactive slots redirect their
     masked writes there, so one fully-batched scatter serves every slot
     without conditionals and without corrupting live pages.  Trash contents
@@ -205,8 +206,8 @@ class PagedAttnCache:
         kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         dt = jnp.dtype(cfg.dtype)
         return PagedAttnCache(
-            k_pages=jnp.zeros((num_pages + 1, page_size, kv, hd), dt),
-            v_pages=jnp.zeros((num_pages + 1, page_size, kv, hd), dt),
+            k_pages=jnp.zeros((num_pages + 1, kv, page_size, hd), dt),
+            v_pages=jnp.zeros((num_pages + 1, kv, page_size, hd), dt),
         )
 
 
@@ -371,7 +372,7 @@ def apply_attention(
             )
         window = cfg.sliding_window or 0
         trash = cache.k_pages.shape[0] - 1
-        page_size = cache.k_pages.shape[1]
+        page_size = cache.k_pages.shape[2]
         mb = paged.block_tables.shape[1]
         if not decode and chunk_lengths is not None:
             # CHUNKED PREFILL / SPEC VERIFY: R slots × C tokens.  Token
@@ -387,8 +388,8 @@ def apply_attention(
             pages_idx = jnp.take_along_axis(paged.block_tables, blk, axis=1)
             pages_idx = jnp.where(valid, pages_idx, trash)         # (R, C)
             offs = tok_pos % page_size
-            kp = cache.k_pages.at[pages_idx, offs].set(k)
-            vp = cache.v_pages.at[pages_idx, offs].set(v)
+            kp = cache.k_pages.at[pages_idx, :, offs].set(k)
+            vp = cache.v_pages.at[pages_idx, :, offs].set(v)
             if chunk_exact:
                 # Speculative verify: scan single-token paged attention over
                 # the chunk so row c is BITWISE the decode step at base + c —
@@ -422,8 +423,8 @@ def apply_attention(
             tok = jnp.arange(s, dtype=jnp.int32)
             pages_idx = paged.block_tables[0, tok // page_size]
             offs = tok % page_size
-            kp = cache.k_pages.at[pages_idx, offs].set(k[0])
-            vp = cache.v_pages.at[pages_idx, offs].set(v[0])
+            kp = cache.k_pages.at[pages_idx, :, offs].set(k[0])
+            vp = cache.v_pages.at[pages_idx, :, offs].set(v[0])
             return _out_proj(out, w_o, ctx, tp_h), PagedAttnCache(kp, vp)
         # DECODE: one token per slot — masked page scatter (inactive slots
         # redirect to the trash page) + the dispatched paged-attention kernel.
@@ -432,8 +433,8 @@ def apply_attention(
         pages_idx = jnp.take_along_axis(paged.block_tables, blk[:, None], axis=1)[:, 0]
         pages_idx = jnp.where(paged.active, pages_idx, trash)
         offs = pos % page_size
-        kp = cache.k_pages.at[pages_idx, offs].set(k[:, 0])
-        vp = cache.v_pages.at[pages_idx, offs].set(v[:, 0])
+        kp = cache.k_pages.at[pages_idx, :, offs].set(k[:, 0])
+        vp = cache.v_pages.at[pages_idx, :, offs].set(v[:, 0])
         out = kernel_ops.paged_attention(
             q[:, 0], kp, vp, paged.block_tables, pos,
             mode=mode, window=window, config=cfg.kernels,

@@ -157,8 +157,8 @@ def _lm_loss(
             xi, li, mi = inp
         logits = logits_sharded(p["embed"], cfg, xi, ctx)
         nll, cnt = cross_entropy_parts(logits, li, cfg, ctx, mi)
-        # rank-1 carry: old-jax shard_map's transpose rejects rank-0 avals
-        # crossing a scan inside the body (parallel/compat.py notes)
+        # (nll, count) summed as one (2,) carry: the same float ops as two
+        # scalar carries
         return carry + jnp.stack([nll, cnt]), None
 
     xs = (xc, lc) if mc is None else (xc, lc, mc)

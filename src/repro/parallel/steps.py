@@ -35,7 +35,6 @@ from repro.kernels.dispatch import KernelConfig
 from repro.models import model as model_api
 from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig, AdamWState, adamw_init, adamw_update
-from repro.parallel import compat
 from repro.parallel import plans as plans_lib
 from repro.parallel.plans import Plan
 
@@ -129,7 +128,7 @@ def build_loss_shard(
 
     in_specs = (param_specs, batch_specs)
     out_specs = (P(rep_entry), {"lm_loss": P(rep_entry), "aux_loss": P(rep_entry)})
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
@@ -150,8 +149,11 @@ def build_train_step(
     replicas = plan.replicas
 
     def total_loss(theta, batch):
+        # replicas share no parameters, so the gradient of the SUM is each
+        # replica's own loss gradient — the scale the per-replica AdamW
+        # clip (clip_norm) is defined on, as in the stacked runtime
         losses, metrics = loss_shard(theta, batch)
-        return jnp.sum(losses) / replicas, (losses, metrics)
+        return jnp.sum(losses), (losses, metrics)
 
     def step(theta, opt, batch):
         (_, (losses, metrics)), grads = jax.value_and_grad(total_loss, has_aux=True)(
@@ -363,7 +365,7 @@ def build_outer_step(
     n_params = 4 if prefetching else 3
     in_specs = (param_specs,) * n_params + (P(rep_entry),)
     out_specs = (param_specs,) * n_params + (P(rep_entry),)
-    fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     sh = plans_lib.shardings(mesh, param_specs)
     step_sh = NamedSharding(mesh, P(rep_entry))
     return jax.jit(
@@ -609,7 +611,7 @@ class OuterProgramPool:
         if compiled:
             self.misses += 1
             t0 = time.time()
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self._programs[key] = build_outer_step(
                     self.plan, self.mesh, self.param_specs, self.outer_cfg, perm,
                     comm_cfg=self.comm_cfg, kernel_cfg=self.kernel_cfg,
@@ -680,7 +682,7 @@ def build_decode_step(
         plan.model_axis if cfg.vocab_size % plan.tp == 0 and plan.tp > 1 else None
     )
     out_specs = (P(dp_entry, None, vocab_entry), cspecs)
-    fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     logits_sh = NamedSharding(mesh, out_specs[0])
     return jax.jit(
         fn,
@@ -719,7 +721,7 @@ def build_prefill_step(
 
     in_specs = (pspecs, cspecs, bspecs)
     out_specs = (P(dp_entry, None, None), cspecs)
-    fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return jax.jit(
         fn,
         in_shardings=(

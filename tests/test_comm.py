@@ -253,10 +253,6 @@ def test_sharded_permute_fused_hlo_collective_count():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from repro.comm import CommConfig
     from repro.core import outer as outer_lib
     from repro.core.outer import OuterConfig
@@ -282,8 +278,8 @@ def test_sharded_permute_fused_hlo_collective_count():
             )
             return new_theta, new_state.phi, new_state.delta
 
-        fn = shard_map(body, mesh=mesh, in_specs=(specs, specs, specs),
-                       out_specs=(specs, specs, specs), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, specs, specs),
+                           out_specs=(specs, specs, specs), check_vma=False)
         hlo = jax.jit(fn).lower(tree, tree, tree).compile().as_text()
         stats = rf.collective_bytes(hlo, model_size=1)
         assert stats.counts["collective-permute"] <= 2, (codec, stats.counts)

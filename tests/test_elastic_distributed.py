@@ -43,7 +43,6 @@ from repro.launch.train_distributed import DistributedTrainer
 from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig
 from repro.parallel import plans as PL, steps as ST
-from repro.parallel.compat import set_mesh
 from repro.sim import FaultPlan, SimCluster
 from repro.train import DistributedProgram, LoopConfig, make_loop
 
@@ -113,7 +112,7 @@ theta_v = jax.tree.map(lambda x: x + jax.random.normal(key, x.shape) * 0.1, vals
 sh = PL.shardings(mesh, pspecs)
 import jax.sharding as jsh
 step_sh = jsh.NamedSharding(mesh, jsh.PartitionSpec("data"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     theta = jax.device_put(theta_v, sh)
     phi = jax.device_put(vals, sh)
     delta = jax.tree.map(jnp.zeros_like, phi)
@@ -164,7 +163,7 @@ delta_v = jax.tree.map(lambda x: jnp.zeros_like(x), vals)
 sh = PL.shardings(mesh, pspecs)
 import jax.sharding as jsh
 step_sh = jsh.NamedSharding(mesh, jsh.PartitionSpec("data"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     th2, phi2, d2, _ = fn(
         jax.device_put(theta_v, sh), jax.device_put(vals, sh),
         jax.device_put(delta_v, sh),
@@ -356,7 +355,7 @@ key = jax.random.PRNGKey(9)
 theta_v = jax.tree.map(lambda x: x + jax.random.normal(key, x.shape) * 0.1, vals)
 sh = PL.shardings(mesh, pspecs)
 step_sh = jsh.NamedSharding(mesh, jsh.PartitionSpec("data"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     th2, phi2, d2, _ = fn(jax.device_put(theta_v, sh), jax.device_put(vals, sh),
                           jax.device_put(jax.tree.map(jnp.zeros_like, vals), sh),
                           jax.device_put(jnp.full((8,), 1, jnp.int32), step_sh))

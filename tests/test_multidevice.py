@@ -36,7 +36,6 @@ from repro.core.outer import OuterConfig
 from repro.core import pairing
 from repro.optim import AdamWConfig
 from repro.launch.mesh import make_test_mesh
-from repro.parallel.compat import set_mesh
 mesh = make_test_mesh(4, 2)
 cfg = ModelConfig(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
                   vocab_size=256, dtype="float32", remat=False)
@@ -55,7 +54,7 @@ B, S = 8, 16
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),(B,S),0,256),
          "labels": jax.random.randint(jax.random.PRNGKey(2),(B,S),0,256)}
 inner = AdamWConfig(lr=1e-3, weight_decay=0.0)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     bundle = ST.build_train_step(cfg, plan, mesh, stacked, batch, inner)
     theta = jax.device_put(vals, bundle.theta_shardings)
     opt = ST.init_opt_state(theta, plan.replicas)
@@ -92,7 +91,7 @@ from repro.core import outer as outer_lib
 pspecs = PL.param_pspecs(plan, mesh, stacked)
 perm_pairs = pairing.ppermute_pairs(0, plan.replicas)
 ocfg = OuterConfig(method="noloco")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     fn = ST.build_outer_step(plan, mesh, pspecs, ocfg, perm_pairs)
     sh = PL.shardings(mesh, pspecs)
     key = jax.random.PRNGKey(5)
@@ -128,7 +127,7 @@ perm_pairs = pairing.ppermute_pairs(0, plan.replicas)
 import jax.sharding as jsh
 rep_sh = jax.ShapeDtypeStruct((plan.replicas,), jnp.int32)
 theta_abs = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), vals)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for method, want, forbid in (("noloco", "collective-permute", "all-reduce"),
                                  ("diloco", "all-reduce", "collective-permute")):
         ocfg = OuterConfig(method=method, alpha=0.3 if method=="diloco" else 0.5)
@@ -157,7 +156,7 @@ cvals, _ = unzip(jax.eval_shape(lambda: caches))
 caches_real = values_of(caches)
 toks = jax.random.randint(jax.random.PRNGKey(3), (B, 1), 0, 256)
 bspecs = ST.batch_pspecs(plan_d, {"tokens": toks})
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     fn, (pspecs, cspecs) = ST.build_decode_step(dcfg, plan_d, mesh, stacked, caches, bspecs)
     theta = jax.device_put(vals, PL.shardings(mesh, pspecs))
     cache_put = jax.device_put(caches_real, PL.shardings(mesh, cspecs))

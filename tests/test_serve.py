@@ -112,8 +112,8 @@ def test_paged_attention_impl_parity(h, kv, mode, window):
     num_pages, page_size, mb, r, d = 6, 4, 4, 3, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (r, h, d))
-    kp = jax.random.normal(ks[1], (num_pages, page_size, kv, d))
-    vp = jax.random.normal(ks[2], (num_pages, page_size, kv, d))
+    kp = jax.random.normal(ks[1], (num_pages, kv, page_size, d))
+    vp = jax.random.normal(ks[2], (num_pages, kv, page_size, d))
     tables = jnp.asarray([[0, 1, 2, 3], [4, 5, 0, 1], [2, 3, 4, 5]], jnp.int32)
     positions = jnp.asarray([5, 11, 2], jnp.int32)
     op = ops.paged_attention(q, kp, vp, tables, positions,
@@ -123,21 +123,45 @@ def test_paged_attention_impl_parity(h, kv, mode, window):
     np.testing.assert_allclose(np.asarray(op), np.asarray(oj), atol=2e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("h,kv,config,want", [
+    (8, 2, PALLAS, "pallas"),  # whole GQA groups fold into the kernel
+    (8, 16, PALLAS, "jnp"),    # a (NP, BS, KV, D) pool read as kv-head major
+    (6, 4, PALLAS, "jnp"),     # ragged groups
+    (8, 2, JNP, "jnp"),
+])
+def test_paged_impl_routes_whole_gqa_groups_only(h, kv, config, want):
+    assert ops.paged_impl(h, kv, config).resolved_impl() == want
+
+
+def test_kernel_bench_refuses_rerouted_rows():
+    """The kernel bench records each row under the dispatched impl, so a
+    shape that routes the op elsewhere must stop it, not fall through."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks import kernel_bench
+
+    kernel_bench.require_route("paged_attention", ops.paged_impl(8, 2, PALLAS), "pallas")
+    with pytest.raises(RuntimeError, match="route to impl=jnp"):
+        kernel_bench.require_route("paged_attention", ops.paged_impl(8, 16, PALLAS), "pallas")
+
+
 def test_paged_attention_masks_unallocated_pages():
     """Entries past positions[r] (stale pages, trash fill) must not leak:
     scrambling them leaves the output bit-unchanged."""
     num_pages, page_size, r, h, d = 4, 4, 2, 2, 8
     q = jax.random.normal(jax.random.fold_in(KEY, 1), (r, h, d))
-    kp = jax.random.normal(jax.random.fold_in(KEY, 2), (num_pages, page_size, h, d))
-    vp = jax.random.normal(jax.random.fold_in(KEY, 3), (num_pages, page_size, h, d))
+    kp = jax.random.normal(jax.random.fold_in(KEY, 2), (num_pages, h, page_size, d))
+    vp = jax.random.normal(jax.random.fold_in(KEY, 3), (num_pages, h, page_size, d))
     tables = jnp.asarray([[0, 1, 2, 3], [0, 1, 2, 3]], jnp.int32)
     positions = jnp.asarray([3, 6], jnp.int32)  # only the first 1-2 pages live
     for cfg in (PALLAS, JNP):
         base = ops.paged_attention(q, kp, vp, tables, positions, config=cfg)
         # scramble everything strictly after each slot's position
         kp2, vp2 = kp.at[2:].set(99.0), vp.at[2:].set(-99.0)
-        kp2 = kp2.at[1, 3:].set(99.0)   # slot 1: page 1 holds pos 4..7, 7 > 6
-        vp2 = vp2.at[1, 3:].set(-99.0)
+        kp2 = kp2.at[1, :, 3:].set(99.0)   # slot 1: page 1 holds pos 4..7, 7 > 6
+        vp2 = vp2.at[1, :, 3:].set(-99.0)
         got = ops.paged_attention(q, kp2, vp2, tables, positions, config=cfg)
         np.testing.assert_array_equal(np.asarray(base), np.asarray(got))
 

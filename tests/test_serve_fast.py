@@ -133,8 +133,8 @@ def test_paged_chunk_attention_impl_parity(h, kv, mode, window):
     num_pages, page_size, mb, r, c, d = 6, 4, 4, 3, 5, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (r, c, h, d))
-    kp = jax.random.normal(ks[1], (num_pages, page_size, kv, d))
-    vp = jax.random.normal(ks[2], (num_pages, page_size, kv, d))
+    kp = jax.random.normal(ks[1], (num_pages, kv, page_size, d))
+    vp = jax.random.normal(ks[2], (num_pages, kv, page_size, d))
     tables = jnp.asarray([[0, 1, 2, 3], [4, 5, 0, 1], [2, 3, 4, 5]], jnp.int32)
     base = jnp.asarray([0, 4, 9], jnp.int32)  # chunk token 0 positions
     op = ops.paged_chunk_attention(q, kp, vp, tables, base,
@@ -150,17 +150,17 @@ def test_paged_chunk_attention_masks_future_and_trash():
     unallocated pages) leaves the output bit-unchanged."""
     num_pages, page_size, r, c, h, d = 4, 4, 2, 3, 2, 8
     q = jax.random.normal(jax.random.fold_in(KEY, 1), (r, c, h, d))
-    kp = jax.random.normal(jax.random.fold_in(KEY, 2), (num_pages, page_size, h, d))
-    vp = jax.random.normal(jax.random.fold_in(KEY, 3), (num_pages, page_size, h, d))
+    kp = jax.random.normal(jax.random.fold_in(KEY, 2), (num_pages, h, page_size, d))
+    vp = jax.random.normal(jax.random.fold_in(KEY, 3), (num_pages, h, page_size, d))
     # disjoint pages per slot; unallocated table entries just repeat a page
     tables = jnp.asarray([[0, 1, 0, 0], [2, 3, 2, 2]], jnp.int32)
     base = jnp.asarray([2, 4], jnp.int32)  # last chunk tokens at pos 4 and 6
     for cfg in (PALLAS, JNP):
         ref = ops.paged_chunk_attention(q, kp, vp, tables, base, config=cfg)
-        kp2 = kp.at[1, 1:].set(77.0)     # slot 0: pos 5..7, all > 4
-        vp2 = vp.at[1, 1:].set(-77.0)
-        kp2 = kp2.at[3, 3:].set(77.0)    # slot 1: pos 7 > 6
-        vp2 = vp2.at[3, 3:].set(-77.0)
+        kp2 = kp.at[1, :, 1:].set(77.0)     # slot 0: pos 5..7, all > 4
+        vp2 = vp.at[1, :, 1:].set(-77.0)
+        kp2 = kp2.at[3, :, 3:].set(77.0)    # slot 1: pos 7 > 6
+        vp2 = vp2.at[3, :, 3:].set(-77.0)
         got = ops.paged_chunk_attention(q, kp2, vp2, tables, base, config=cfg)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 

@@ -208,7 +208,7 @@ def test_eval_batches_helper():
 
 
 # ---------------------------------------------------------------------------
-# Distributed runtime (jax-version differences handled by parallel/compat)
+# Distributed runtime
 # ---------------------------------------------------------------------------
 
 
@@ -219,12 +219,13 @@ def test_distributed_entry_resumes():
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src")
     )
     with tempfile.TemporaryDirectory() as d:
         args = [sys.executable, "-m", "repro.launch.train_distributed",
-                "--data", "4", "--model", "2", "--steps", "8",
+                "--reduced", "--data", "4", "--model", "2", "--steps", "8",
                 "--inner-steps", "4", "--ckpt-dir", d, "--ckpt-every", "4"]
         out = subprocess.run(args, capture_output=True, text=True, env=env,
                              timeout=560)
