@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.comm import CommConfig, bytes_model, stream_partition
 from repro.configs import registry
 from repro.core.elastic import ElasticContext
@@ -119,7 +120,10 @@ class DistributedTrainer:
                 steps_lib.init_opt_state(theta, self.plan.replicas),
                 self.bundle.opt_shardings,
             )
-            phi = jax.device_put(vals, self.bundle.theta_shardings)
+            # its own buffers: where the sharding is already the arrays'
+            # (one replica on one device) a plain device_put returns them,
+            # and the inner step's donation of theta would delete phi
+            phi = jax.device_put(vals, self.bundle.theta_shardings, may_alias=False)
             delta = jax.tree.map(jnp.zeros_like, phi)
             step_c = jax.device_put(
                 jnp.zeros((self.plan.replicas,), jnp.int32),
@@ -207,7 +211,13 @@ class DistributedTrainer:
 
     # -- steps ---------------------------------------------------------------
 
+    def stage_batch(self, batch: dict) -> dict:
+        """Place global ``(R*B, S)`` rows on the mesh, sharded by replica."""
+        with jax.set_mesh(self.mesh):
+            return jax.device_put(batch, plans_lib.shardings(self.mesh, self._bspecs))
+
     def inner_step(self, state, batch):
+        """One inner step on a batch placed by :meth:`stage_batch`."""
         mask = self._active_mask()
         snap = None
         if mask is not None:
@@ -219,8 +229,8 @@ class DistributedTrainer:
                 self._take_rows(state["opt"], ids),
             )
         with jax.set_mesh(self.mesh):
-            batch = jax.device_put(batch, plans_lib.shardings(self.mesh, self._bspecs))
-            theta, opt, metrics = self.bundle.step_fn(state["theta"], state["opt"], batch)
+            with obs.span("train.dispatch"):
+                theta, opt, metrics = self.bundle.step_fn(state["theta"], state["opt"], batch)
             if snap is not None:
                 theta = self._put_rows(theta, ids, snap[0])
                 opt = self._put_rows(opt, ids, snap[1])
@@ -239,9 +249,20 @@ class DistributedTrainer:
             # lazy XLA compile — the churn-induced stall telemetry measures
             for ev in self.pool.drain_events():
                 self.recompile_events.append(dict(
-                    ev, wall_s=round(time.time() - t0, 4),
+                    ev, wall_s=round(time.perf_counter() - t0, 4),
                     outer_index=outer_index,
                 ))
+
+    def _run_outer(self, state, fn, info, outer_index: int, *,
+                   prefetch: bool = False) -> dict:
+        """Call one outer program on ``state`` (with the φ-prefetch buffer
+        when ``prefetch``), and log a pool miss's recompile event."""
+        keys = ("theta", "phi", "delta") + (("phi_pre",) if prefetch else ()) + ("outer_step",)
+        t0 = time.perf_counter()
+        with obs.span("outer.dispatch"), jax.set_mesh(self.mesh):
+            out = fn(*(state[k] for k in keys))
+        self._drain_compiles(info, t0, outer_index)
+        return dict(state, **dict(zip(keys, out)))
 
     def maybe_outer_step(self, state):
         if self._streaming:
@@ -249,33 +270,28 @@ class DistributedTrainer:
         if state["inner_step"] % self.outer_cfg.inner_steps:
             return state, False
         outer_index = state["inner_step"] // self.outer_cfg.inner_steps - 1
-        if self.elastic is None:
-            fn, info = self.pool.program(outer_index)
-        else:
-            partner_fn = None
-            if self.outer_cfg.method == "noloco":
-                # the ppermute pairs ARE the audit table: dst indexed by src
-                def partner_fn(parts):
-                    return self._table_of(self.pool.pairs_for(
-                        outer_index, parts, self.elastic.partition
-                    )[1])
+        with obs.span("train.outer_step", outer_index=outer_index, stream=0) as sp:
+            with obs.span("outer.plan"):
+                if self.elastic is None:
+                    fn, info = self.pool.program(outer_index)
+                else:
+                    partner_fn = None
+                    if self.outer_cfg.method == "noloco":
+                        # the ppermute pairs ARE the audit table: dst indexed by src
+                        def partner_fn(parts):
+                            return self._table_of(self.pool.pairs_for(
+                                outer_index, parts, self.elastic.partition
+                            )[1])
 
-            plan = self.elastic.plan_round(partner_fn)
-            if plan.all_absent:
-                fn, info = self._all_absent_program(outer_index)
-            else:
-                fn, info = self.pool.program(
-                    outer_index, plan.participants, self.elastic.partition
-                )
-        t0 = time.time()
-        with jax.set_mesh(self.mesh):
-            theta, phi, delta, step_c = fn(
-                state["theta"], state["phi"], state["delta"], state["outer_step"]
-            )
-            new = dict(state, theta=theta, phi=phi, delta=delta,
-                       outer_step=step_c)
-        self._drain_compiles(info, t0, outer_index)
-        return new, True
+                    plan = self.elastic.plan_round(partner_fn)
+                    if plan.all_absent:
+                        fn, info = self._all_absent_program(outer_index)
+                    else:
+                        fn, info = self.pool.program(
+                            outer_index, plan.participants, self.elastic.partition
+                        )
+            sp.set_metadata(compiled=info["compiled"])
+            return self._run_outer(state, fn, info, outer_index), True
 
     def outer_step_async(self, state, *, sync_index: int, due, staleness):
         """One merged sync tick of the asynchronous clock on the compiled
@@ -305,37 +321,32 @@ class DistributedTrainer:
                 sync_index, parts, self.elastic.partition
             )[1])
 
-        plan = self.elastic.plan_round(partner_fn)
-        if plan.all_absent:
-            fn, info = self._all_absent_program(sync_index)
-        else:
-            due = np.asarray(due, dtype=bool)
-            tau = np.asarray(staleness)
-            update = due.copy()
-            if plan.active is not None:
-                update &= np.asarray(plan.active, dtype=bool)
-            if update.all() and not tau.any():
-                # everyone due, nobody late: the legacy synchronous program
-                fn, info = self.pool.program(
-                    sync_index, plan.participants, self.elastic.partition
-                )
-            else:
-                stale_host = None
-                if self.outer_cfg.stale == "momentum" and tau.any():
-                    stale_host = tau
-                fn, info = self.pool.program(
-                    sync_index, plan.participants, self.elastic.partition,
-                    update_mask=update, staleness=stale_host,
-                )
-        t0 = time.time()
-        with jax.set_mesh(self.mesh):
-            theta, phi, delta, step_c = fn(
-                state["theta"], state["phi"], state["delta"], state["outer_step"]
-            )
-            new = dict(state, theta=theta, phi=phi, delta=delta,
-                       outer_step=step_c)
-        self._drain_compiles(info, t0, sync_index)
-        return new, True
+        with obs.span("train.outer_step", outer_index=sync_index, stream=0) as sp:
+            with obs.span("outer.plan"):
+                plan = self.elastic.plan_round(partner_fn)
+                if plan.all_absent:
+                    fn, info = self._all_absent_program(sync_index)
+                else:
+                    due = np.asarray(due, dtype=bool)
+                    tau = np.asarray(staleness)
+                    update = due.copy()
+                    if plan.active is not None:
+                        update &= np.asarray(plan.active, dtype=bool)
+                    if update.all() and not tau.any():
+                        # everyone due, nobody late: the legacy synchronous program
+                        fn, info = self.pool.program(
+                            sync_index, plan.participants, self.elastic.partition
+                        )
+                    else:
+                        stale_host = None
+                        if self.outer_cfg.stale == "momentum" and tau.any():
+                            stale_host = tau
+                        fn, info = self.pool.program(
+                            sync_index, plan.participants, self.elastic.partition,
+                            update_mask=update, staleness=stale_host,
+                        )
+            sp.set_metadata(compiled=info["compiled"])
+            return self._run_outer(state, fn, info, sync_index), True
 
     def _maybe_stream_sync(self, state):
         """One stream's staggered sync on the compiled shard_map path.
@@ -351,11 +362,37 @@ class DistributedTrainer:
         if k is None:
             return state, False
         i = self._schedule.sync_index(k, t)
-        streams = self._schedule.stream_count
         overlap = self.comm_cfg.overlap
         epoch = 0 if self.elastic is None else self.elastic.epoch
-        groups = None if self.elastic is None else self.elastic.partition
+        with obs.span("train.outer_step", outer_index=i, stream=k) as sp:
+            with obs.span("outer.plan"):
+                fn, info, absent, consume, next_table = self._plan_stream_sync(
+                    state, k, i, epoch)
+            sp.set_metadata(compiled=info["compiled"])
+            had_prefetch = bool(self._pre_epoch[k] >= 0) and not absent
+            if absent:
+                # every live replica timed out: freeze everything, advance the
+                # sync counter (the shared whole-payload all-absent program —
+                # no per-stream variant needed since nothing moves), and
+                # invalidate this stream's prefetch: its pre-send was planned
+                # for THIS sync and none was issued for the next one
+                new = self._run_outer(state, fn, info, i)
+                self._pre_epoch[k] = -1
+            else:
+                new = self._run_outer(state, fn, info, i, prefetch=overlap)
+                if overlap:
+                    self._pre_partner[k] = next_table
+                    self._pre_epoch[k] = epoch
+            self._record_stream_event(k, i, consume=consume,
+                                      had_prefetch=had_prefetch)
+            return new, True
 
+    def _plan_stream_sync(self, state, k: int, i: int, epoch: int):
+        """The program of stream ``k``'s sync ``i``: returns ``(fn, info,
+        all_absent, consume, next_table)``, where ``next_table`` is the
+        pairing the φ′ pre-send travels on (None without overlap)."""
+        overlap = self.comm_cfg.overlap
+        groups = None if self.elastic is None else self.elastic.partition
         participants = None
         if self.elastic is None:
             partner_table = self._table_of(self.pool.pairs_for(i)[1])
@@ -365,69 +402,27 @@ class DistributedTrainer:
 
             plan = self.elastic.plan_round(partner_fn)
             if plan.all_absent:
-                # every live replica timed out: freeze everything, advance the
-                # sync counter (the shared whole-payload all-absent program —
-                # no per-stream variant needed since nothing moves), and
-                # invalidate this stream's prefetch: its pre-send was planned
-                # for THIS sync and none was issued for the next one
-                fn, info = self._all_absent_program(i)
-                t0 = time.time()
-                with jax.set_mesh(self.mesh):
-                    theta, phi, delta, step_c = fn(
-                        state["theta"], state["phi"], state["delta"],
-                        state["outer_step"],
-                    )
-                new = dict(state, theta=theta, phi=phi, delta=delta,
-                           outer_step=step_c)
-                self._drain_compiles(info, t0, i)
-                self._pre_epoch[k] = -1
-                self._record_stream_event(k, i, consume=False,
-                                          had_prefetch=False)
-                return new, True
+                return (*self._all_absent_program(i), True, False, None)
             participants = plan.participants
             partner_table = np.asarray(plan.partner, dtype=np.int64)
 
-        had_prefetch = bool(self._pre_epoch[k] >= 0)
         consume = bool(
             overlap and "phi_pre" in state
             and self._pre_epoch[k] == epoch
             and np.array_equal(self._pre_partner[k], partner_table)
         )
-        presend_index = i + streams if overlap else None
+        presend_index = i + self._schedule.stream_count if overlap else None
         presend_membership = None if self.elastic is None else self.elastic.membership
         next_table = None
         if overlap:
             next_table = self._table_of(self.pool.pairs_for(
                 presend_index, presend_membership, groups
             )[1])
-
         fn, info = self.pool.program(
             i, participants, groups, stream=k, consume=consume,
             presend_index=presend_index, presend_membership=presend_membership,
         )
-        t0 = time.time()
-        with jax.set_mesh(self.mesh):
-            if overlap:
-                theta, phi, delta, phi_pre, step_c = fn(
-                    state["theta"], state["phi"], state["delta"],
-                    state["phi_pre"], state["outer_step"],
-                )
-                new = dict(state, theta=theta, phi=phi, delta=delta,
-                           phi_pre=phi_pre, outer_step=step_c)
-            else:
-                theta, phi, delta, step_c = fn(
-                    state["theta"], state["phi"], state["delta"],
-                    state["outer_step"],
-                )
-                new = dict(state, theta=theta, phi=phi, delta=delta,
-                           outer_step=step_c)
-        self._drain_compiles(info, t0, i)
-        if overlap:
-            self._pre_partner[k] = next_table
-            self._pre_epoch[k] = epoch
-        self._record_stream_event(k, i, consume=consume,
-                                  had_prefetch=had_prefetch)
-        return new, True
+        return fn, info, False, consume, next_table
 
     def _record_stream_event(self, k: int, i: int, *, consume: bool,
                              had_prefetch: bool) -> None:
@@ -464,7 +459,7 @@ class DistributedTrainer:
         key = "all-absent"  # identity pairing — the slot is irrelevant
         if key not in self.pool._programs:
             self.pool.misses += 1
-            t0 = time.time()
+            t0 = time.perf_counter()
             with jax.set_mesh(self.mesh):
                 self.pool._programs[key] = steps_lib.build_outer_step(
                     self.plan, self.mesh, self.bundle.pspecs, self.outer_cfg,
@@ -474,7 +469,7 @@ class DistributedTrainer:
                 )
             self.pool.events.append({
                 "slot": key, "view": "all-absent", "epoch": None,
-                "build_s": round(time.time() - t0, 4),
+                "build_s": round(time.perf_counter() - t0, 4),
                 "pool_size": len(self.pool._programs),
             })
             return self.pool._programs[key], {
@@ -488,9 +483,9 @@ class DistributedTrainer:
         }
 
     def eval_loss(self, state, batch):
-        """Grad-free per-replica losses (R,) via the bundle's eval program."""
+        """Grad-free per-replica losses (R,) via the bundle's eval program,
+        on a batch placed by :meth:`stage_batch`."""
         with jax.set_mesh(self.mesh):
-            batch = jax.device_put(batch, plans_lib.shardings(self.mesh, self._bspecs))
             return self.bundle.eval_fn(state["theta"], batch)
 
     def theta_struct(self):

@@ -610,7 +610,7 @@ class OuterProgramPool:
         build_s = 0.0
         if compiled:
             self.misses += 1
-            t0 = time.time()
+            t0 = time.perf_counter()
             with jax.set_mesh(self.mesh):
                 self._programs[key] = build_outer_step(
                     self.plan, self.mesh, self.param_specs, self.outer_cfg, perm,
@@ -619,7 +619,7 @@ class OuterProgramPool:
                     partition=self.partition,
                     consume_prefetch=consume, perm_presend=perm_presend,
                 )
-            build_s = time.time() - t0
+            build_s = time.perf_counter() - t0
             self.events.append({
                 "slot": str(slot), "view": "full" if view is None else "elastic",
                 "epoch": None if membership is None else membership.epoch,

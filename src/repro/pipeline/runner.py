@@ -25,6 +25,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.comm import CommConfig
 from repro.core import metrics as metrics_lib
 from repro.core import pairing
@@ -288,6 +289,10 @@ class PipelineTrainer:
         # inner steps (calling twice at the same step is a no-op)
         if state["step"] < (k + 1) * m:
             return state, False
+        with obs.span("train.outer_step", outer_index=k, stream=0):
+            return self._outer_round(state, k), True
+
+    def _outer_round(self, state: dict, k: int) -> dict:
         round_plan = None
         active = None
         if self.elastic is not None:
@@ -323,12 +328,11 @@ class PipelineTrainer:
             new_params.append(new_theta)
             new_phi.append(new_ost.phi)
             new_delta.append(new_ost.delta)
-        new_state = dict(
+        return dict(
             state,
             params=new_params,
             outer={"phi": new_phi, "delta": new_delta, "step": k + 1},
         )
-        return new_state, True
 
     # -- grad-free eval --------------------------------------------------------
 

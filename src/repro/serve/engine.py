@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import model as M
 from repro.models.attention import PagedAttnCache, PagedView
 from repro.models.config import ModelConfig
@@ -373,69 +374,81 @@ class ServeEngine:
         """Per-request stats attached at eviction; spec engines override."""
         return {}
 
-    def _emit_tokens(self, slot: int, occ: dict, out_buf, upto: int) -> None:
+    def _emit_tokens(self, slot: int, occ: dict, out_buf, upto: int) -> int:
         """Stream tokens [emitted, upto) of a slot to the token callback,
         stamped with their decode DISPATCH times (host times; exact when
-        sync_each_step, otherwise early by the device queue depth)."""
+        sync_each_step, otherwise early by the device queue depth).  Returns
+        how many tokens it streamed."""
         if self._token_cb is None:
-            return
+            return 0
         req: Request = occ["req"]
         upto = min(upto, req.max_new)
-        for i in range(occ["emitted"], upto):
+        first = occ["emitted"]
+        for i in range(first, upto):
             t = occ["t_toks"][i] if i < len(occ["t_toks"]) else time.perf_counter()
             self._token_cb(req.rid, i, int(out_buf[slot, i]), t)
-        occ["emitted"] = max(occ["emitted"], upto)
+        occ["emitted"] = max(first, upto)
+        return max(upto - first, 0)
 
     def drain(self) -> None:
         """Flush generated-but-unstreamed tokens to the token callback with
         ONE device_get for the whole batch — the periodic streaming path (the
         free path being the eviction-wave read in :meth:`_evict_finished`).
         Never called per token: decode stays sync-free."""
-        if self._token_cb is None:
-            return
-        pending = [
-            (slot, occ) for slot, occ in enumerate(self._slots)
-            if occ is not None and occ["phase"] == "decode"
-            and occ["emitted"] < min(occ["steps"], occ["req"].max_new)
-        ]
-        if not pending:
-            return
-        out_buf = np.asarray(jax.device_get(self.state.out_buf))
-        for slot, occ in pending:
-            self._emit_tokens(slot, occ, out_buf, min(occ["steps"], occ["req"].max_new))
+        with obs.span("serve.drain"):
+            if self._token_cb is None:
+                return
+            pending = [
+                (slot, occ) for slot, occ in enumerate(self._slots)
+                if occ is not None and occ["phase"] == "decode"
+                and occ["emitted"] < min(occ["steps"], occ["req"].max_new)
+            ]
+            if not pending:
+                return
+            with obs.span("serve.fetch"):
+                out_buf = np.asarray(jax.device_get(self.state.out_buf))
+            with obs.span("serve.emit") as sp:
+                n = sum(
+                    self._emit_tokens(slot, occ, out_buf, min(occ["steps"], occ["req"].max_new))
+                    for slot, occ in pending
+                )
+                sp.set_metadata(tokens=n)
 
     def _evict_finished(self) -> list[FinishedRequest]:
         done: list[FinishedRequest] = []
         out_buf = None
-        for slot, occ in enumerate(self._slots):
-            if (
-                occ is None or occ["phase"] != "decode"
-                or occ["steps"] < occ["req"].max_new
-            ):
-                continue
-            if out_buf is None:  # one device_get serves every eviction this step
-                out_buf = np.asarray(jax.device_get(self.state.out_buf))
-            req: Request = occ["req"]
-            toks = out_buf[slot, : req.max_new].tolist()
-            self._emit_tokens(slot, occ, out_buf, req.max_new)
-            done.append(
-                FinishedRequest(
-                    rid=req.rid, prompt=req.prompt, tokens=toks,
-                    submit_t=req.submit_t, admit_t=occ["admit_t"],
-                    finish_t=time.perf_counter(),
-                    stats=self._finish_stats(occ),
+        with obs.span("serve.evict") as sp:
+            for slot, occ in enumerate(self._slots):
+                if (
+                    occ is None or occ["phase"] != "decode"
+                    or occ["steps"] < occ["req"].max_new
+                ):
+                    continue
+                if out_buf is None:  # one device_get serves every eviction this step
+                    with obs.span("serve.fetch"):
+                        out_buf = np.asarray(jax.device_get(self.state.out_buf))
+                req: Request = occ["req"]
+                toks = out_buf[slot, : req.max_new].tolist()
+                self._emit_tokens(slot, occ, out_buf, req.max_new)
+                done.append(
+                    FinishedRequest(
+                        rid=req.rid, prompt=req.prompt, tokens=toks,
+                        submit_t=req.submit_t, admit_t=occ["admit_t"],
+                        finish_t=time.perf_counter(),
+                        stats=self._finish_stats(occ),
+                    )
                 )
-            )
-            self.alloc.free(occ["blocks"])
-            self._slots[slot] = None
-            st = self.state
-            self.state = dataclasses.replace(
-                st,
-                active=st.active.at[slot].set(False),
-                positions=st.positions.at[slot].set(0),
-                tokens=st.tokens.at[slot].set(0),
-                out_len=st.out_len.at[slot].set(0),
-            )
+                self.alloc.free(occ["blocks"])
+                self._slots[slot] = None
+                st = self.state
+                self.state = dataclasses.replace(
+                    st,
+                    active=st.active.at[slot].set(False),
+                    positions=st.positions.at[slot].set(0),
+                    tokens=st.tokens.at[slot].set(0),
+                    out_len=st.out_len.at[slot].set(0),
+                )
+            sp.set_metadata(evicted=len(done))
         return done
 
     def _admit(self) -> None:
@@ -448,62 +461,72 @@ class ServeEngine:
             if not self.alloc.can_alloc(need):
                 break  # head-of-line blocks until pages free up (no preempt)
             self.queue.pop(0)
-            slot = free.pop(0)
-            if self._chunk_fn is not None:
-                # chunked path: pages leave the free list under a lease
-                # (committed when the last chunk lands), the slot parks in
-                # "prefill" phase and _advance_prefills walks it forward
-                lease = self.alloc.reserve(need)
-                row = np.full((self._mb,), self.alloc.trash_page, np.int32)
-                row[: len(lease.blocks)] = lease.blocks
-                row_dev = jnp.asarray(row)
-                st = self.state
-                self.state = dataclasses.replace(
-                    st, block_tables=st.block_tables.at[slot].set(row_dev)
-                )
-                self._slots[slot] = {
-                    "req": req, "lease": lease, "row": row_dev,
-                    "phase": "prefill", "cursor": 0, "rec": None,
-                    "admit_t": 0.0, "steps": 0, "t_toks": [], "emitted": 0,
-                }
-                continue
-            blocks = self.alloc.alloc(need)
-            row = np.full((self._mb,), self.alloc.trash_page, np.int32)
-            row[: len(blocks)] = blocks
-            row_dev = jnp.asarray(row)
+            claim_t = time.perf_counter()
+            with obs.span(
+                "serve.admit_request", rid=req.rid,
+                queued_ms=(claim_t - req.submit_t) * 1e3,
+                prompt_tokens=len(req.prompt), pages=need,
+            ):
+                self._claim_slot(free.pop(0), req, need, claim_t)
 
+    def _claim_slot(self, slot: int, req: Request, need: int, claim_t: float) -> None:
+        """Give ``req`` the free ``slot`` and ``need`` pages; ``claim_t`` is
+        when it left the queue."""
+        if self._chunk_fn is not None:
+            # chunked path: pages leave the free list under a lease
+            # (committed when the last chunk lands), the slot parks in
+            # "prefill" phase and _advance_prefills walks it forward
+            lease = self.alloc.reserve(need)
+            row = np.full((self._mb,), self.alloc.trash_page, np.int32)
+            row[: len(lease.blocks)] = lease.blocks
+            row_dev = jnp.asarray(row)
             st = self.state
-            # scratch shares the page-pool buffers with st.caches; prefill
-            # donates them and _merge keeps the returned (written) pools
-            scratch = self._prefill_caches(st.caches)
-            key = jax.random.fold_in(jax.random.fold_in(_SAMPLE_KEY, req.rid), 0)
-            tok0, new_caches = self._prefill_fn(
-                self.params,
-                jnp.asarray(req.prompt, jnp.int32),
-                scratch,
-                row_dev,
-                jnp.float32(req.temperature),
-                key,
-            )
-            merged = self._merge_caches(st.caches, new_caches, slot)
             self.state = dataclasses.replace(
-                st,
-                caches=merged,
-                block_tables=st.block_tables.at[slot].set(row_dev),
-                tokens=st.tokens.at[slot].set(tok0),
-                positions=st.positions.at[slot].set(len(req.prompt)),
-                active=st.active.at[slot].set(True),
-                temps=st.temps.at[slot].set(req.temperature),
-                rids=st.rids.at[slot].set(req.rid),
-                out_buf=st.out_buf.at[slot, 0].set(tok0),
-                out_len=st.out_len.at[slot].set(1),
-                budgets=st.budgets.at[slot].set(req.max_new),
+                st, block_tables=st.block_tables.at[slot].set(row_dev)
             )
-            now = time.perf_counter()
             self._slots[slot] = {
-                "req": req, "blocks": blocks, "phase": "decode",
-                "admit_t": now, "steps": 1, "t_toks": [now], "emitted": 0,
+                "req": req, "lease": lease, "row": row_dev,
+                "phase": "prefill", "cursor": 0, "rec": None, "claim_t": claim_t,
+                "admit_t": 0.0, "steps": 0, "t_toks": [], "emitted": 0,
             }
+            return
+        blocks = self.alloc.alloc(need)
+        row = np.full((self._mb,), self.alloc.trash_page, np.int32)
+        row[: len(blocks)] = blocks
+        row_dev = jnp.asarray(row)
+
+        st = self.state
+        # scratch shares the page-pool buffers with st.caches; prefill
+        # donates them and _merge keeps the returned (written) pools
+        scratch = self._prefill_caches(st.caches)
+        key = jax.random.fold_in(jax.random.fold_in(_SAMPLE_KEY, req.rid), 0)
+        tok0, new_caches = self._prefill_fn(
+            self.params,
+            jnp.asarray(req.prompt, jnp.int32),
+            scratch,
+            row_dev,
+            jnp.float32(req.temperature),
+            key,
+        )
+        merged = self._merge_caches(st.caches, new_caches, slot)
+        self.state = dataclasses.replace(
+            st,
+            caches=merged,
+            block_tables=st.block_tables.at[slot].set(row_dev),
+            tokens=st.tokens.at[slot].set(tok0),
+            positions=st.positions.at[slot].set(len(req.prompt)),
+            active=st.active.at[slot].set(True),
+            temps=st.temps.at[slot].set(req.temperature),
+            rids=st.rids.at[slot].set(req.rid),
+            out_buf=st.out_buf.at[slot, 0].set(tok0),
+            out_len=st.out_len.at[slot].set(1),
+            budgets=st.budgets.at[slot].set(req.max_new),
+        )
+        now = time.perf_counter()
+        self._slots[slot] = {
+            "req": req, "blocks": blocks, "phase": "decode", "claim_t": claim_t,
+            "admit_t": now, "steps": 1, "t_toks": [now], "emitted": 0,
+        }
 
     def _prefill_chunk_step(self, slot: int) -> None:
         """Advance one prefill-phase slot by one fixed-width chunk through the
@@ -575,19 +598,55 @@ class ServeEngine:
             for slot in pending:
                 if budget <= 0:
                     return
-                self._prefill_chunk_step(slot)
+                occ = self._slots[slot]
+                base = occ["cursor"]
+                n = min(self.scfg.prefill_chunk, len(occ["req"].prompt) - base)
+                with obs.span("serve.prefill_chunk", slot=slot, tokens=n, base=base):
+                    self._prefill_chunk_step(slot)
                 budget -= self.scfg.prefill_chunk
 
     def step(self) -> list[FinishedRequest]:
         """One scheduler tick: evict → admit → prefill chunks → fused decode."""
-        done = self._evict_finished()
-        self._admit()
-        self._advance_prefills()
-        if any(
-            s is not None and s["phase"] == "decode"
-            and s["steps"] < s["req"].max_new
-            for s in self._slots
-        ):
+        with obs.span("serve.step", **self._tick_stats()):
+            done = self._evict_finished()
+            with obs.span("serve.admit"):
+                self._admit()
+            self._advance_prefills()
+            if any(
+                s is not None and s["phase"] == "decode"
+                and s["steps"] < s["req"].max_new
+                for s in self._slots
+            ):
+                self._decode()
+        return done
+
+    def _tick_stats(self) -> dict:
+        """The ``serve.step`` span's stats: the queue, the free pages and
+        the slots in decode at the start of the tick (only while traced)."""
+        if not obs.enabled():
+            return {}
+        return {
+            "queue_depth": len(self.queue),
+            "free_pages": self.alloc.free_count,
+            "decode_slots": sum(
+                s is not None and s["phase"] == "decode" for s in self._slots
+            ),
+        }
+
+    def _decode_stats(self) -> dict:
+        """The slots the decode step advances and the cache positions it
+        attends over in all (prompt plus tokens so far, per slot)."""
+        if not obs.enabled():
+            return {}
+        live = [
+            len(s["req"].prompt) + s["steps"] for s in self._slots
+            if s is not None and s["phase"] == "decode"
+        ]
+        return {"active_slots": len(live), "live_tokens": sum(live)}
+
+    def _decode(self) -> None:
+        """One fused, donated decode step over every slot."""
+        with obs.span("serve.decode", **self._decode_stats()):
             t0 = time.perf_counter()
             self.state = self._decode_fn(self.params, self.state)
             if self.scfg.sync_each_step:
@@ -601,7 +660,6 @@ class ServeEngine:
                     if occ["steps"] < occ["req"].max_new:
                         occ["t_toks"].append(now)
                     occ["steps"] += 1
-        return done
 
     @property
     def idle(self) -> bool:

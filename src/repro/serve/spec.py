@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import model as M
 from repro.models.attention import PagedAttnCache, PagedView
 from repro.models.config import ModelConfig
@@ -331,15 +332,9 @@ class SpecServeEngine(ServeEngine):
 
     # -- decode: one speculative round per tick -----------------------------
 
-    def step(self):
-        done = self._evict_finished()
-        self._admit()
-        self._advance_prefills()
-        if any(
-            s is not None and s["phase"] == "decode"
-            and s["steps"] < s["req"].max_new
-            for s in self._slots
-        ):
+    def _decode(self) -> None:
+        """One speculative round over every slot."""
+        with obs.span("serve.spec_round") as sp:
             t0 = time.perf_counter()
             new_state, new_draft, commit, accepted = self._spec_fn(
                 self.params, self.draft_params, self.state, self.draft_caches
@@ -347,13 +342,15 @@ class SpecServeEngine(ServeEngine):
             self.state = new_state
             self.draft_caches = new_draft
             # the round's one host sync: k tokens' worth of scheduling state
-            commits = np.asarray(jax.device_get(commit))
-            accepts = np.asarray(jax.device_get(accepted))
+            with obs.span("serve.fetch"):
+                commits = np.asarray(jax.device_get(commit))
+                accepts = np.asarray(jax.device_get(accepted))
             now = time.perf_counter()
             if self.scfg.sync_each_step:
                 self.decode_step_times.append(now - t0)
             self.decode_steps += 1
             self.spec_rounds += 1
+            prop0, acc0 = self.spec_prop_total, self.spec_accept_total
             for slot, occ in enumerate(self._slots):
                 if occ is None or occ["phase"] != "decode":
                     continue
@@ -374,7 +371,8 @@ class SpecServeEngine(ServeEngine):
                     if occ["steps"] < occ["req"].max_new:
                         occ["t_toks"].append(now)
                     occ["steps"] += 1
-        return done
+            sp.set_metadata(proposed=self.spec_prop_total - prop0,
+                            accepted=self.spec_accept_total - acc0)
 
     def _finish_stats(self, occ: dict) -> dict:
         prop = occ.get("spec_prop", 0)

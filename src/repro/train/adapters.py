@@ -33,6 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.comm import CommConfig, bytes_model
 from repro.comm import payload as payload_lib
 from repro.core import metrics as metrics_lib
@@ -265,15 +266,19 @@ class GossipProgram(_ElasticSurface):
         return self.trainer.init(stacked)
 
     def inner_step(self, state, batch, rng):
-        active = self._active_arr()
-        if active is None:
-            return self._inner_jit(state, batch, rng)
-        state, metrics = self._inner_jit(state, batch, rng, active)
-        # frozen replicas' stale-weight losses are not training signal: the
-        # loop's mean (and telemetry) sees active replicas only, consistent
-        # with eval_step/weight_std
-        ids = jnp.asarray(self.elastic.active_ids())
-        metrics = dict(metrics, loss=jnp.take(metrics["loss"], ids))
+        # the step counter lives on the device here: the span carries none
+        with obs.span("train.inner_step"):
+            active = self._active_arr()
+            args = (state, batch, rng) + (() if active is None else (active,))
+            with obs.span("train.dispatch"):
+                state, metrics = self._inner_jit(*args)
+            if active is None:
+                return state, metrics
+            # frozen replicas' stale-weight losses are not training signal:
+            # the loop's mean (and telemetry) sees active replicas only,
+            # consistent with eval_step/weight_std
+            ids = jnp.asarray(self.elastic.active_ids())
+            metrics = dict(metrics, loss=jnp.take(metrics["loss"], ids))
         return state, metrics
 
     def maybe_outer_step(self, state):
@@ -281,20 +286,23 @@ class GossipProgram(_ElasticSurface):
             return self._maybe_stream_sync(state)
         if not self.trainer.should_sync(state):
             return state, False
-        partner_fn = None
-        if self.tcfg.outer.method == "noloco":
-            step = int(state.outer.step)
+        with obs.span("train.outer_step", stream=0):
+            with obs.span("outer.plan"):
+                partner_fn = None
+                if self.tcfg.outer.method == "noloco":
+                    step = int(state.outer.step)
 
-            def partner_fn(parts):
-                return pairing_lib.elastic_partner_table(
-                    step, parts, seed=self.tcfg.outer.seed,
-                    groups=self.elastic.partition,
-                )
+                    def partner_fn(parts):
+                        return pairing_lib.elastic_partner_table(
+                            step, parts, seed=self.tcfg.outer.seed,
+                            groups=self.elastic.partition,
+                        )
 
-        plan = self.elastic.plan_round(partner_fn)
-        partner = None if plan.partner is None else jnp.asarray(plan.partner)
-        active = None if plan.active is None else jnp.asarray(plan.active)
-        return self.trainer.outer_step(state, partner=partner, active=active), True
+                plan = self.elastic.plan_round(partner_fn)
+                partner = None if plan.partner is None else jnp.asarray(plan.partner)
+                active = None if plan.active is None else jnp.asarray(plan.active)
+            with obs.span("outer.dispatch"):
+                return self.trainer.outer_step(state, partner=partner, active=active), True
 
     def outer_step_async(self, state, *, sync_index: int, due, staleness):
         """One merged sync tick of the asynchronous clock (DESIGN.md §7).
@@ -309,6 +317,10 @@ class GossipProgram(_ElasticSurface):
         legacy synchronous call, bit for bit."""
         if self.tcfg.outer.method != "noloco":
             raise ValueError("asynchronous merged-tick sync is NoLoCo-only")
+        with obs.span("train.outer_step", outer_index=sync_index, stream=0):
+            return self._async_sync(state, sync_index, due, staleness)
+
+    def _async_sync(self, state, sync_index: int, due, staleness):
         seed = self.tcfg.outer.seed
 
         def partner_fn(parts):
@@ -356,6 +368,10 @@ class GossipProgram(_ElasticSurface):
         if k is None:
             return state, False
         i = self._schedule.sync_index(k, t)
+        with obs.span("train.outer_step", outer_index=i, stream=k):
+            return self._stream_sync(state, k, i)
+
+    def _stream_sync(self, state, k: int, i: int):
         streams = self._schedule.stream_count
         seed = self.tcfg.outer.seed
         overlap = self.tcfg.comm.overlap
@@ -365,7 +381,8 @@ class GossipProgram(_ElasticSurface):
                 i, parts, seed=seed, groups=self.elastic.partition
             )
 
-        plan = self.elastic.plan_round(partner_fn)
+        with obs.span("outer.plan"):
+            plan = self.elastic.plan_round(partner_fn)
         partner = jnp.asarray(plan.partner)
         active = None if plan.active is None else jnp.asarray(plan.active)
 
@@ -385,11 +402,12 @@ class GossipProgram(_ElasticSurface):
             )
             partner_next = jnp.asarray(next_table)
 
-        state, phi_pre_out = self.trainer.outer_step_stream(
-            state, stream=k, partition=self._partition, partner=partner,
-            active=active, phi_pre=self._phi_pre, consume_prefetch=consume,
-            partner_next=partner_next,
-        )
+        with obs.span("outer.dispatch"):
+            state, phi_pre_out = self.trainer.outer_step_stream(
+                state, stream=k, partition=self._partition, partner=partner,
+                active=active, phi_pre=self._phi_pre, consume_prefetch=consume,
+                partner_next=partner_next,
+            )
         if phi_pre_out is not None:
             self._phi_pre = phi_pre_out
             self._pre_partner[k] = np.asarray(next_table)
@@ -575,11 +593,17 @@ class DistributedProgram(_ElasticSurface):
     def init_state(self, example_batch: dict):
         return self.trainer.init_state(self._to_global(example_batch))
 
+    def _stage(self, batch: dict) -> dict:
+        return self.trainer.stage_batch(self._to_global(batch))
+
     def inner_step(self, state, batch, rng):
-        state, metrics = self.trainer.inner_step(state, self._to_global(batch))
-        ids = self._active_ids()
-        if ids is not None:
-            metrics = dict(metrics, loss=jnp.take(metrics["loss"], ids))
+        with obs.span("train.inner_step", step=state["inner_step"]):
+            with obs.span("train.stage_batch"):
+                batch = self._stage(batch)
+            state, metrics = self.trainer.inner_step(state, batch)
+            ids = self._active_ids()
+            if ids is not None:
+                metrics = dict(metrics, loss=jnp.take(metrics["loss"], ids))
         return state, metrics
 
     def maybe_outer_step(self, state):
@@ -591,7 +615,7 @@ class DistributedProgram(_ElasticSurface):
         )
 
     def eval_step(self, state, batch, rng) -> float:
-        losses = self.trainer.eval_loss(state, self._to_global(batch))
+        losses = self.trainer.eval_loss(state, self._stage(batch))
         ids = self._active_ids()
         if ids is not None:
             losses = jnp.take(losses, ids)
@@ -707,7 +731,9 @@ class PipelineProgram(_ElasticSurface):
         return self.trainer.init(jax.random.PRNGKey(self.trainer.seed))
 
     def inner_step(self, state, batch, rng):
-        state, loss = self.trainer.train_step(state, batch)
+        with obs.span("train.inner_step", step=state["step"]):
+            with obs.span("train.dispatch"):
+                state, loss = self.trainer.train_step(state, batch)
         return state, {"loss": jnp.asarray(loss)}
 
     def maybe_outer_step(self, state):

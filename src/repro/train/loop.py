@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import checkpoint as ckpt_lib
+from repro import obs
 from repro.train.program import TrainProgram
 
 __all__ = ["LoopConfig", "TrainLoop", "make_loop"]
@@ -83,19 +84,21 @@ class TrainLoop:
         if self._jsonl is None:
             return
         rec = {"event": event, "run": self.cfg.run_name, **fields}
-        self._jsonl.write(json.dumps(rec) + "\n")
-        self._jsonl.flush()
+        with obs.span("train.telemetry"):
+            self._jsonl.write(json.dumps(rec) + "\n")
+            self._jsonl.flush()
 
     # -- checkpointing -------------------------------------------------------
 
     def _save(self, step: int, state, rngs: dict) -> str:
-        tree = {
-            "program": self.program.state_pytree(state),
-            "loop": {"step": np.int64(step), **rngs},
-        }
-        path = ckpt_lib.save(
-            self.cfg.ckpt_dir, step, tree, keep=self.cfg.ckpt_keep
-        )
+        with obs.span("train.checkpoint", step=step):
+            tree = {
+                "program": self.program.state_pytree(state),
+                "loop": {"step": np.int64(step), **rngs},
+            }
+            path = ckpt_lib.save(
+                self.cfg.ckpt_dir, step, tree, keep=self.cfg.ckpt_keep
+            )
         self._emit("ckpt", step=step, path=path)
         return path
 
@@ -156,15 +159,16 @@ class TrainLoop:
         # elastic programs expose an epoch-stamped Membership; emit a
         # telemetry event whenever the view changes (drop / rejoin)
         last_epoch = getattr(self.program, "membership_epoch", None)
-        t0 = time.time()
+        t0 = time.perf_counter()
 
         for t in range(start_step, cfg.steps):
             batch = {k: jnp.asarray(v) for k, v in next(loader).items()}
-            step_t0 = time.time()
+            step_t0 = time.perf_counter()
             state, metrics = self.program.inner_step(
                 state, batch, jax.random.fold_in(rngs["train_key"], t)
             )
-            loss = float(jnp.mean(metrics["loss"]))
+            with obs.span("train.loss_fetch"):
+                loss = float(jnp.mean(metrics["loss"]))
             losses.append(loss)
             total_tokens += int(np.prod(batch["tokens"].shape))
             state, synced = self.program.maybe_outer_step(state)
@@ -196,10 +200,10 @@ class TrainLoop:
                     "membership", step=t + 1, epoch=epoch,
                     num_active=mem.num_active, active=list(mem.active_ids),
                 )
-            dt = time.time() - step_t0
+            dt = time.perf_counter() - step_t0
             self._emit(
                 "step", step=t + 1, loss=loss, dt_s=round(dt, 6),
-                tokens_per_s=round(total_tokens / max(time.time() - t0, 1e-9), 1),
+                tokens_per_s=round(total_tokens / max(time.perf_counter() - t0, 1e-9), 1),
             )
             if synced:
                 outer_syncs += 1
@@ -230,25 +234,26 @@ class TrainLoop:
                         blocking_bytes=cost.blocking_bytes if cost else 0,
                     )
             if cfg.eval_every and (t + 1) % cfg.eval_every == 0 and self.eval_set:
-                ev = float(np.mean([
-                    self.program.eval_step(
-                        state, b, jax.random.fold_in(rngs["eval_key"], t)
-                    )
-                    for b in self.eval_set
-                ]))
-                wstd = float(self.program.weight_std(state))
+                with obs.span("train.eval", step=t + 1):
+                    ev = float(np.mean([
+                        self.program.eval_step(
+                            state, b, jax.random.fold_in(rngs["eval_key"], t)
+                        )
+                        for b in self.eval_set
+                    ]))
+                    wstd = float(self.program.weight_std(state))
                 evals.append((t + 1, ev))
                 weight_stds.append((t + 1, wstd))
                 self._emit("eval", step=t + 1, eval_loss=ev, weight_std=wstd)
                 if cfg.log:
                     print(
                         f"step {t+1}: train={loss:.4f} eval={ev:.4f} "
-                        f"wstd={wstd:.6f} ({time.time()-t0:.0f}s)", flush=True
+                        f"wstd={wstd:.6f} ({time.perf_counter()-t0:.0f}s)", flush=True
                     )
             if cfg.ckpt_dir and cfg.ckpt_every and (t + 1) % cfg.ckpt_every == 0:
                 self._save(t + 1, state, rngs)
 
-        wall = time.time() - t0
+        wall = time.perf_counter() - t0
         already_saved = (
             cfg.ckpt_every and cfg.steps % cfg.ckpt_every == 0
         )
