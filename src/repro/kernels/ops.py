@@ -88,8 +88,8 @@ def flash_attention(
     *,
     mode: str = "causal",
     window: int = 0,
-    block_q: int = 128,
-    block_kv: int = 128,
+    block_q: int | None = None,
+    block_kv: int | None = None,
     unroll: bool = False,
     config: KernelConfig | None = None,
 ) -> jax.Array:
@@ -98,6 +98,8 @@ def flash_attention(
     K/V stay at kv-head width end to end: the Pallas path folds the G = H/KV
     query heads per kv head into the q row dimension, the jnp path groups the
     einsums — neither materializes K/V expanded to all query heads.
+    The Pallas path's tiles left ``None`` come from
+    :func:`repro.kernels.flash_attention.plan`.
     ``unroll`` unrolls the jnp path's KV scan (dry-run cost analysis)."""
     impl, interpret = _resolve(config)
     return _attention_op(mode, window, block_q, block_kv, impl, interpret, unroll)(

@@ -15,7 +15,9 @@ for the device.  A stat that costs more than O(1) is computed only under
 Importing this module also registers one ``jax.monitoring`` listener: every
 backend compile that finishes while a profiler runs leaves a zero-length
 ``jax.compile`` marker with the compile's seconds as stat ``secs``, so the
-device trace shows which step recompiled.
+device trace shows which step recompiled.  ``mark(name, **stats)`` leaves
+such a marker: the flash-attention kernel leaves ``jax.flash_plan`` (its
+tiles and live tile pairs) each time it is traced under a profiler.
 
 These spans are the timing view of the boundaries whose events the
 training loop's JSONL stream and the outer-program pool's ``recompile``
@@ -27,7 +29,7 @@ from __future__ import annotations
 import jax
 from jax.profiler import TraceAnnotation
 
-__all__ = ["span", "enabled"]
+__all__ = ["span", "enabled", "mark"]
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -42,10 +44,17 @@ def enabled() -> bool:
     return TraceAnnotation.is_enabled()
 
 
-def _mark_compile(event: str, secs: float, **_kwargs) -> None:
-    if event == COMPILE_EVENT and enabled():
-        with TraceAnnotation("jax.compile", secs=float(secs)):
+def mark(name: str, **stats) -> None:
+    """A zero-length marker ``name`` with ``stats``, left only while a
+    profiler runs."""
+    if enabled():
+        with TraceAnnotation(name, **stats):
             pass
+
+
+def _mark_compile(event: str, secs: float, **_kwargs) -> None:
+    if event == COMPILE_EVENT:
+        mark("jax.compile", secs=float(secs))
 
 
 jax.monitoring.register_event_duration_secs_listener(_mark_compile)

@@ -98,6 +98,10 @@ KERNELS = {
         lambda q, k, v: flash_attention.pallas_flash_attention(q, k, v, interpret=False),
         [((8, 1024, 16, 48), BF)] * 3,
     ),
+    "flash_attention_r1_d64": (   # train.stablelm2.r1: batch 2 x 2048, 32 heads of 64
+        lambda q, k, v: flash_attention.pallas_flash_attention(q, k, v, interpret=False),
+        [((2, 2048, 32, 64), BF)] * 3,
+    ),
     "flash_attention_gqa_d128": (
         lambda q, k, v: flash_attention.pallas_flash_attention(q, k, v, interpret=False),
         [((4, 1024, 16, 128), BF), ((4, 1024, 8, 128), BF), ((4, 1024, 8, 128), BF)],

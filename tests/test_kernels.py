@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import flash_attention, ops, ref
 from repro.kernels.dispatch import KernelConfig
 
 KEY = jax.random.PRNGKey(0)
@@ -36,19 +36,91 @@ def _gold_attention(q, k, v, mode, window):
 
 
 @pytest.mark.parametrize("shape", [
-    (1, 128, 128, 4, 4, 64),
-    (2, 256, 256, 4, 2, 64),   # GQA (grouped fold, no K/V expansion)
-    (1, 256, 256, 2, 1, 128),  # MQA, d=128
-    (1, 200, 200, 2, 2, 64),   # non-block-multiple
-    (1, 128, 384, 2, 2, 64),   # cross lengths
+    # (b, sq, sk, h, kv, d, forced (block_q, block_kv) or None for the plan, dtype)
+    (1, 128, 128, 4, 4, 64, None, jnp.float32),
+    (2, 256, 256, 4, 2, 64, None, jnp.float32),    # GQA (grouped fold, no K/V expansion)
+    (1, 256, 256, 2, 1, 128, None, jnp.float32),   # MQA, d=128
+    (1, 200, 200, 2, 2, 64, None, jnp.float32),    # non-block-multiple
+    (1, 128, 384, 2, 2, 64, None, jnp.float32),    # cross lengths
+    # small unequal tiles, so that many tile pairs are skipped
+    (1, 1024, 1024, 4, 2, 64, (256, 128), jnp.float32),   # folded GQA
+    (1, 1024, 1024, 2, 2, 64, (128, 256), jnp.float32),
+    (1, 200, 200, 2, 2, 64, (64, 128), jnp.float32),
+    (1, 128, 384, 2, 2, 64, (64, 128), jnp.float32),
+    (1, 1024, 1024, 4, 2, 64, (256, 128), jnp.bfloat16),  # bf16 in, f32 reference
 ])
-@pytest.mark.parametrize("mode,window", [("causal", 0), ("local", 64), ("full", 0)])
+@pytest.mark.parametrize(
+    "mode,window", [("causal", 0), ("local", 64), ("full", 0), ("local", 300)]
+)
 def test_flash_attention_sweep(shape, mode, window):
-    b, sq, sk, h, kv, d = shape
-    q, k, v = _qkv(b, sq, sk, h, kv, d, jnp.float32)
-    out = ops.flash_attention(q, k, v, mode=mode, window=window, config=PALLAS)
-    gold = _gold_attention(q, k, v, mode, window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(gold), atol=3e-5, rtol=1e-4)
+    b, sq, sk, h, kv, d, tiles, dtype = shape
+    block_q, block_kv = tiles or (None, None)
+    q, k, v = _qkv(b, sq, sk, h, kv, d, dtype)
+    out = ops.flash_attention(q, k, v, mode=mode, window=window,
+                              block_q=block_q, block_kv=block_kv, config=PALLAS)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    gold = _gold_attention(*f32, mode, window)
+    atol, rtol = (3e-5, 1e-4) if dtype == jnp.float32 else (1e-2, 1e-2)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(gold), atol=atol, rtol=rtol
+    )
+
+
+def _brute_bounds(sq_pad, sk, q_stride, bq, bk, mode, window):
+    """(live, dense) kv-tile index sets of each q tile, from the mask."""
+    q_pos = np.arange(sq_pad) % q_stride
+    k_pos = np.arange(-(-sk // bk) * bk)
+    valid = (k_pos[None, :] < sk) & np.ones((sq_pad, 1), bool)
+    if mode != "full":
+        valid &= k_pos[None, :] <= q_pos[:, None]
+    if mode == "local":
+        valid &= k_pos[None, :] > q_pos[:, None] - window
+    out = []
+    for qi in range(sq_pad // bq):
+        rows = valid[qi * bq:(qi + 1) * bq]
+        tiles = [rows[:, j * bk:(j + 1) * bk] for j in range(len(k_pos) // bk)]
+        out.append(({j for j, t in enumerate(tiles) if t.any()},
+                    {j for j, t in enumerate(tiles) if t.all()}))
+    return out
+
+
+@pytest.mark.parametrize("sq_pad,sk,d,mode,window,q_stride,tiles", [
+    (2048, 2048, 64, "causal", 0, 2048, None),          # r1's shape, planned tiles
+    (2048, 2048, 64, "causal", 0, 2048, (128, 128)),    # r1's shape, the old tiles
+    (2048, 2048, 64, "local", 300, 2048, None),
+    (2048, 2048, 128, "full", 0, 2048, None),
+    (4096, 1024, 64, "causal", 0, 1024, (256, 128)),    # folded GQA, G = 4
+    (4096, 1024, 64, "local", 64, 1024, (256, 128)),
+    (4096, 1024, 64, "local", 300, 1024, (128, 256)),
+    (4096, 1024, 64, "full", 0, 1024, (256, 128)),
+    (512, 200, 64, "causal", 0, 256, (64, 128)),        # non-multiple length
+    (512, 200, 64, "local", 64, 256, None),
+    (128, 384, 64, "causal", 0, 128, (64, 128)),        # cross lengths
+    (128, 384, 64, "full", 0, 128, None),
+    (8192, 8192, 256, "causal", 0, 8192, None),         # widest head
+])
+def test_flash_plan_counts_live_tiles(sq_pad, sk, d, mode, window, q_stride, tiles):
+    block_q, block_kv = tiles or (None, None)
+    p = flash_attention.plan(sq_pad, sk, d, mode, window, q_stride,
+                             block_q=block_q, block_kv=block_kv)
+    if tiles is None:
+        assert q_stride % p.block_q == 0
+        assert (-(-sk // 128) * 128) % p.block_kv == 0
+        assert p.block_q in flash_attention.Q_TILES
+        assert p.block_kv in flash_attention.KV_TILES
+        assert flash_attention.vmem_bytes(p.block_q, p.block_kv, d) <= flash_attention.VMEM_LIMIT
+    else:
+        assert (p.block_q, p.block_kv) == tiles
+    brute = _brute_bounds(sq_pad, sk, q_stride, p.block_q, p.block_kv, mode, window)
+    nk = -(-sk // p.block_kv)
+    assert p.tiles == len(brute) * nk
+    assert p.live_tiles == sum(len(live) for live, _ in brute)
+    for qi, (live, dense) in enumerate(brute):
+        lo, hi, dlo, dhi = p.bounds[qi % len(p.bounds)]
+        assert set(range(lo, hi + 1)) == live
+        assert set(range(dlo, dhi + 1)) == dense
+    if (sq_pad, sk, mode, tiles) == (2048, 2048, "causal", None):
+        assert (p.block_q, p.block_kv, p.live_tiles, p.tiles) == (512, 512, 10, 16)
 
 
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 3e-5), (jnp.bfloat16, 3e-2)])

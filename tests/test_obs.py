@@ -15,6 +15,7 @@ import pytest
 from repro import obs
 from repro.core.outer import OuterConfig
 from repro.data import LoaderConfig
+from repro.kernels import flash_attention
 from repro.launch.mesh import make_mesh
 from repro.launch.train_distributed import DistributedTrainer
 from repro.models import model as M
@@ -200,6 +201,21 @@ def test_no_compile_marker_after_warm_up(tmp_path, params):
     assert marks and all(m.stats["secs"] > 0 for m in marks)
 
 
+def test_flash_kernel_leaves_its_plan_when_traced(tmp_path):
+    q = jax.random.normal(jax.random.PRNGKey(0), (2, 384, 16))
+
+    def call():
+        return flash_attention.flash_attention_bhsd(
+            q, q, q, mode="causal", block_q=128, block_kv=128).block_until_ready()
+
+    _, spans = traced(tmp_path, call)
+    marks = named(spans, "jax.flash_plan")
+    assert [m.stats for m in marks] == [{"block_q": 128, "block_kv": 128, "live_tiles": 6, "tiles": 9}]
+    # the marker is left when the kernel is traced, not when it runs
+    _, spans = traced(tmp_path, call)
+    assert not named(spans, "jax.flash_plan")
+
+
 # ---------------------------------------------------------------------------
 # training
 # ---------------------------------------------------------------------------
@@ -283,6 +299,6 @@ def test_span_names_in_the_program_keep_to_their_prefixes():
     names = set()
     for f in src.rglob("*.py"):
         text = f.read_text()
-        names |= set(re.findall(r"""(?:obs\.span|TraceAnnotation)\(\s*["']([^"']+)["']""", text))
+        names |= set(re.findall(r"""(?:obs\.span|obs\.mark|TraceAnnotation)\(\s*["']([^"']+)["']""", text))
         assert "bench." not in "".join(re.findall(r"""span\(\s*["'][^"']*["']""", text)), f
     assert names and all(n.startswith(PREFIXES) for n in names), names
